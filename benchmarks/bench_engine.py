@@ -37,6 +37,7 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config
 from repro.runtime.engine_config import EngineConfig
 from repro.runtime.scheduler import (ContinuousBatchingScheduler,
@@ -290,4 +291,5 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     sys.exit(main())

@@ -12,12 +12,14 @@ import os
 
 _BODY = """
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"  # host devices only; never the chip
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count={n}"
 import sys; sys.path.insert(0, {src!r})
 import time
 import jax, jax.numpy as jnp
 from repro.core.parfor import parfor, count_collectives
-mesh = jax.make_mesh(({n},), ("data",))
+from repro.core.sharding import make_mesh
+mesh = make_mesh(({n},), ("data",))
 w = jax.random.normal(jax.random.PRNGKey(0), (64, 16))
 x = jax.random.normal(jax.random.PRNGKey(1), (512, 64))
 fn = lambda rows: parfor(lambda r: jax.nn.softmax(r @ w, -1), rows, mesh=mesh)[0]
@@ -42,8 +44,8 @@ def run():
                            capture_output=True, text=True, timeout=300)
         line = [ln for ln in r.stdout.splitlines() if ln.startswith("RESULT")]
         if not line:
-            rows.append(f"parfor_scaling_w{n},0,ERROR={r.stderr[-200:]}")
-            continue
+            raise RuntimeError(f"parfor_scaling_w{n} child failed: "
+                               f"{r.stderr[-400:]}")
         _, us, colls, rows_per = line[0].split(",")
         rows.append(
             f"parfor_scaling_w{n},{us},collectives={colls};rows_per_worker={rows_per}")

@@ -45,6 +45,7 @@ import sys
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config
 from repro.runtime.engine import ServingEngine
 from repro.runtime.engine_config import EngineConfig
@@ -281,4 +282,5 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     sys.exit(main())

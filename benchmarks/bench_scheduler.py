@@ -41,6 +41,7 @@ from dataclasses import replace
 
 import jax.numpy as jnp
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config
 from repro.models.model import build_model
 from repro.runtime.engine_config import EngineConfig
@@ -276,4 +277,5 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     sys.exit(main())

@@ -8,7 +8,10 @@ Prints ``name,us_per_call,derived`` CSV per the harness contract:
   * roofline terms from the dry-run artifacts (deliverable g)
 """
 
+import sys
 import traceback
+
+from repro.compile_cache import enable_compile_cache
 
 from benchmarks import (bench_engine, bench_kernels,
                         bench_operator_selection, bench_parfor,
@@ -16,8 +19,10 @@ from benchmarks import (bench_engine, bench_kernels,
                         bench_roofline, bench_router)
 
 
-def main() -> None:
+def main() -> int:
+    enable_compile_cache()
     print("name,us_per_call,derived")
+    failed = []
     for mod in (bench_operator_selection, bench_plan_selection,
                 bench_plan_cache, bench_engine, bench_router, bench_parfor,
                 bench_kernels, bench_roofline):
@@ -27,7 +32,13 @@ def main() -> None:
         except Exception as e:  # noqa: BLE001
             print(f"{mod.__name__},0,ERROR={type(e).__name__}:{e}")
             traceback.print_exc()
+            failed.append(mod.__name__)
+    if failed:
+        print(f"# {len(failed)} bench(es) failed: {', '.join(failed)}",
+              file=sys.stderr)
+        return 1
+    return 0
 
 
 if __name__ == '__main__':
-    main()
+    sys.exit(main())

@@ -14,8 +14,8 @@ from dataclasses import dataclass, field
 from typing import Optional, Tuple
 
 # ---------------------------------------------------------------------------
-# Hardware characteristics (TPU v5e target; the runtime here is CPU-only and
-# these constants feed the cost model / roofline, not execution).
+# Hardware characteristics: the plan compiler's cost model and memory budget.
+# A server on a TPU takes the figures of the chip it finds (TPU_SPECS).
 # ---------------------------------------------------------------------------
 
 
@@ -26,11 +26,29 @@ class HardwareSpec:
     hbm_bandwidth: float = 819e9        # bytes/s per chip
     ici_bandwidth: float = 50e9         # bytes/s per ICI link
     hbm_bytes: int = 16 * 1024**3       # per-chip HBM capacity
-    vmem_bytes: int = 128 * 1024 * 1024  # per-core VMEM (v5e ~128 MiB)
     mxu_dim: int = 128                  # systolic array tile edge
 
 
 TPU_V5E = HardwareSpec()
+
+# Published per-chip figures keyed by JAX's ``device_kind`` (Google Cloud
+# documentation, "TPU v5e"). A TPU kind missing here is an error, never a
+# default: a plan sized for the wrong chip is not a plan.
+TPU_SPECS = {"TPU v5 lite": TPU_V5E}
+
+
+def hardware_for(device_kind: str) -> HardwareSpec:
+    if device_kind not in TPU_SPECS:
+        raise ValueError(f"no HardwareSpec for TPU device kind "
+                         f"{device_kind!r}; add its published figures to "
+                         f"repro.config.TPU_SPECS")
+    return TPU_SPECS[device_kind]
+
+# Scoped VMEM a Pallas kernel may use: the Mosaic compiler's default limit on
+# v5e (16 MiB of the 128 MiB physical). The main-path kernels pass it to
+# the compiler explicitly, and the dispatcher (kernels/ops.py) and the plan
+# compiler test a kernel's block working set against this one number.
+VMEM_LIMIT_BYTES = 16 * 1024 * 1024
 
 
 # ---------------------------------------------------------------------------

@@ -25,7 +25,6 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 # Below this many rows per device, distributing is not worth it (SystemML's
 # local-parfor decision for small task sets).
@@ -87,12 +86,12 @@ def parfor(
                 s = s / rows.shape[0]
             return s
 
-    fn = shard_map(
+    fn = jax.shard_map(
         shard_body,
         mesh=mesh,
         in_specs=(in_spec,),
         out_specs=out_spec,
-        check_rep=False,
+        check_vma=False,
     )
     return fn(rows), plan
 

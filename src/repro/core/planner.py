@@ -21,6 +21,7 @@ from typing import Iterator
 
 from repro.config import (
     TPU_V5E,
+    VMEM_LIMIT_BYTES,
     HardwareSpec,
     InputShape,
     MeshConfig,
@@ -30,8 +31,12 @@ from repro.config import (
 from repro.core.cost import analytic_cost, decode_kernel_seconds
 from repro.core.memory import ACT_BYTES, cache_page_count, estimate_memory
 from repro.core.strategies import ExecutionPlan, PlanConfig, RuntimeStats, Strategy
+from repro.kernels.paged_attention import paged_block_bytes
 
 LONG_CONTEXT_THRESHOLD = 262_144  # beyond this, full attention must window
+# note on a plan emitted although no candidate fits the HBM budget: kept for
+# the analytic dry-run; a server on a real chip refuses such a plan
+OVER_HBM_BUDGET = "WARNING: worst-case estimate exceeds HBM budget"
 
 
 class PlanCompiler:
@@ -93,12 +98,12 @@ class PlanCompiler:
         if shape.kind != "decode" or page <= 0:
             rec.update(reason="dense (non-paged) serving path")
             return rec
-        # device-memory fit of the kernel's per-block set: one K and one V
-        # physical page + the (g, D) query group + f32 accumulator scratch
-        d = model.head_dim
-        g = model.q_per_kv
-        blk = 2 * page * d * ACT_BYTES + g * d * ACT_BYTES + g * (d + 2) * 4
-        rec["vmem_fit"] = blk <= self.hw.vmem_bytes * 0.8
+        # device-memory fit of the kernel's per-block set (one K and one V
+        # physical page with every kv head, the query group, f32 scratch)
+        # against the scoped VMEM limit the kernel compiles with
+        blk = paged_block_bytes(page, model.num_kv_heads, model.q_per_kv,
+                                model.head_dim, ACT_BYTES)
+        rec["vmem_fit"] = blk <= VMEM_LIMIT_BYTES
         if not rec["vmem_fit"]:
             rec.update(reason=f"page block {blk}B exceeds VMEM budget")
             return rec
@@ -175,7 +180,7 @@ class PlanCompiler:
             # exactly like SystemML emitting a distributed plan that spills.
             chosen = candidates[-1].replace(
                 notes=candidates[-1].notes
-                + ("WARNING: worst-case estimate exceeds HBM budget",)
+                + (OVER_HBM_BUDGET,)
             )
             chosen_mem = estimate_memory(model, shape, mesh, chosen, train, self.hw,
                                          dtype,

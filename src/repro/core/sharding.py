@@ -14,7 +14,7 @@ from __future__ import annotations
 from typing import Optional, Sequence, Tuple
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from repro.config import MeshConfig
 from repro.core.strategies import PlanConfig
@@ -52,6 +52,15 @@ FSDP_AXES = (
 
 # Axes that must never shard (scan-stacked layer dim, small vectors).
 NEVER_SHARD = ("layers", "head_dim", "ssm_state", "conv", "scalar", "window")
+
+
+def make_mesh(shape: Sequence[int], axes: Sequence[str]) -> Mesh:
+    """``jax.make_mesh`` with Auto axes. The models place tensors with
+    sharding constraints and let GSPMD propagate the rest; JAX's default
+    Explicit axes would instead demand an output sharding on every
+    ambiguous gather and contraction."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def _axis_size(mesh: MeshConfig, names: Sequence[str]) -> int:

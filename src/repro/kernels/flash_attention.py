@@ -19,9 +19,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.config import VMEM_LIMIT_BYTES
+
 NEG_INF = -1e30
 
 BQ, BK = 128, 128
+MIN_BLOCK = 8
 
 
 def _flash_kernel(
@@ -58,9 +61,9 @@ def _flash_kernel(
     p = jnp.where(mask, p, 0.0)
     alpha = jnp.exp(m_prev - m_new)
     l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
+    # f32 accumulation: Mosaic refuses a bf16-accumulating MXU dot
     acc_ref[...] = alpha * acc_ref[...] + jax.lax.dot(
-        p.astype(v_ref.dtype), v_ref[0]
-    ).astype(jnp.float32)
+        p.astype(v_ref.dtype), v_ref[0], preferred_element_type=jnp.float32)
     m_ref[...] = m_new
 
     @pl.when(ki == n_kv - 1)
@@ -91,8 +94,10 @@ def flash_attention(
     g = hq // hkv
     if q_offset < 0:
         q_offset = sk - sq
-    bq = min(bq, _pow2_floor(sq))
-    bk = min(bk, _pow2_floor(sk))
+    # blocks are at least MIN_BLOCK rows (the TPU sublane tile), so a short
+    # prompt is padded up to one aligned block instead of shrinking below it
+    bq = max(MIN_BLOCK, min(bq, _pow2_floor(sq)))
+    bk = max(MIN_BLOCK, min(bk, _pow2_floor(sk)))
     sqp, skp = _pad(sq, bq), _pad(sk, bk)
     if sqp != sq:
         q = jnp.pad(q, ((0, 0), (0, 0), (0, sqp - sq), (0, 0)))
@@ -128,6 +133,7 @@ def flash_attention(
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, d), jnp.float32),
         ],
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(qr, k.reshape(b * hkv, skp, d), v.reshape(b * hkv, skp, d))
     return out.reshape(b, hq, sqp, d)[:, :, :sq, :]

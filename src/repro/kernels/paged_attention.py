@@ -9,8 +9,10 @@ with jnp gathers *around* the flash kernel, materializing a gathered
 before attending. This kernel fuses the indirection into the attention
 itself:
 
-- grid ``(B, Hkv, n_pages)`` — one block row per (request, kv head), the
-  page axis minor (sequential) so online-softmax state lives in VMEM;
+- grid ``(B, n_pages)`` — one block row per request, the page axis minor
+  (sequential) so online-softmax state lives in VMEM; each K/V block is one
+  physical page with every kv head, ``(page, Hkv, D)``, the tile-aligned
+  block of the ``(slots, Hkv, D)`` stack;
 - the page table and per-row ``pos`` ride in as *scalar prefetch* operands
   (``PrefetchScalarGridSpec``), so the K/V BlockSpec index_maps read the
   table entry and DMA the physical page directly — no gathered copy exists;
@@ -45,23 +47,24 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.config import VMEM_LIMIT_BYTES
 from repro.kernels.ref import NEG_INF, phys_slots
 
 
 def _paged_decode_kernel(
     tables_ref,  # (B, n_pages) int32, scalar prefetch
     pos_ref,     # (B,) int32, scalar prefetch
-    q_ref,       # (1, 1, g, D)
-    k_ref,       # (page, 1, D) — the physical page picked by the index_map
-    v_ref,       # (page, 1, D)
-    o_ref,       # (1, 1, g, D)
-    m_ref,       # (g, 1) f32 scratch
-    l_ref,       # (g, 1) f32 scratch
-    acc_ref,     # (g, D) f32 scratch
-    *, page: int, n_pages: int, sc: int, g: int, scale: float,
+    q_ref,       # (1, Hkv, g, D)
+    k_ref,       # (page, Hkv, D) — the physical page picked by the index_map
+    v_ref,       # (page, Hkv, D)
+    o_ref,       # (1, Hkv, g, D)
+    m_ref,       # (Hkv, g, 1) f32 scratch
+    l_ref,       # (Hkv, g, 1) f32 scratch
+    acc_ref,     # (Hkv, g, D) f32 scratch
+    *, page: int, n_pages: int, sc: int, hkv: int, g: int, scale: float,
 ):
     b = pl.program_id(0)
-    j = pl.program_id(2)
+    j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
@@ -73,28 +76,33 @@ def _paged_decode_kernel(
 
     @pl.when(j * page < n_valid)
     def _accumulate():
-        q = q_ref[0, 0].astype(jnp.float32) * scale            # (g, d)
-        k = k_ref[:, 0, :].astype(jnp.float32)                 # (page, d)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))  # (g, page)
         islot = j * page + jax.lax.broadcasted_iota(jnp.int32, (g, page), 1)
         mask = islot < n_valid
-        s = jnp.where(mask, s, NEG_INF)
+        # the block carries every kv head of the page (a (page, 1, D) block
+        # of the (slots, Hkv, D) stack is not tile-aligned); heads are a
+        # short static loop
+        for h in range(hkv):
+            q = q_ref[0, h].astype(jnp.float32) * scale            # (g, d)
+            k = k_ref[:, h, :].astype(jnp.float32)                 # (page, d)
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            s = jnp.where(mask, s, NEG_INF)                        # (g, page)
 
-        m_prev = m_ref[...]                                    # (g, 1)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
-        acc_ref[...] = alpha * acc_ref[...] + jax.lax.dot(
-            p, v_ref[:, 0, :].astype(jnp.float32)
-        )
-        m_ref[...] = m_new
+            m_prev = m_ref[h]                                      # (g, 1)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+            alpha = jnp.exp(m_prev - m_new)
+            l_ref[h] = alpha * l_ref[h] + jnp.sum(p, axis=1, keepdims=True)
+            acc_ref[h] = alpha * acc_ref[h] + jax.lax.dot(
+                p, v_ref[:, h, :].astype(jnp.float32),
+                preferred_element_type=jnp.float32)
+            m_ref[h] = m_new
 
     @pl.when(j == n_pages - 1)
     def _done():
         lsum = l_ref[...]
         safe = jnp.where(lsum == 0.0, 1.0, lsum)
-        o_ref[0, 0] = (acc_ref[...] / safe).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / safe).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("page", "sc", "interpret"))
@@ -118,14 +126,14 @@ def paged_decode_attention(
 
     # Sentinel / out-of-range table entries are clamped to a real page at
     # DMA time; the committed-slot mask keeps their scores out of the sum.
-    def kv_map(b, h, j, tables_ref, pos_ref):
+    def kv_map(b, j, tables_ref, pos_ref):
         del pos_ref
-        return (jnp.minimum(tables_ref[b, j], n_phys - 1), h, 0)
+        return (jnp.minimum(tables_ref[b, j], n_phys - 1), 0, 0)
 
-    grid = (bsz, hkv, n_pages)
+    grid = (bsz, n_pages)
     kernel = functools.partial(
-        _paged_decode_kernel, page=page, n_pages=n_pages, sc=sc, g=g,
-        scale=1.0 / (d ** 0.5),
+        _paged_decode_kernel, page=page, n_pages=n_pages, sc=sc, hkv=hkv,
+        g=g, scale=1.0 / (d ** 0.5),
     )
     out = pl.pallas_call(
         kernel,
@@ -133,21 +141,31 @@ def paged_decode_attention(
             num_scalar_prefetch=2,
             grid=grid,
             in_specs=[
-                pl.BlockSpec((1, 1, g, d), lambda b, h, j, *_: (b, h, 0, 0)),
-                pl.BlockSpec((page, 1, d), kv_map),
-                pl.BlockSpec((page, 1, d), kv_map),
+                pl.BlockSpec((1, hkv, g, d), lambda b, j, *_: (b, 0, 0, 0)),
+                pl.BlockSpec((page, hkv, d), kv_map),
+                pl.BlockSpec((page, hkv, d), kv_map),
             ],
-            out_specs=pl.BlockSpec((1, 1, g, d), lambda b, h, j, *_: (b, h, 0, 0)),
+            out_specs=pl.BlockSpec((1, hkv, g, d), lambda b, j, *_: (b, 0, 0, 0)),
             scratch_shapes=[
-                pltpu.VMEM((g, 1), jnp.float32),
-                pltpu.VMEM((g, 1), jnp.float32),
-                pltpu.VMEM((g, d), jnp.float32),
+                pltpu.VMEM((hkv, g, 1), jnp.float32),
+                pltpu.VMEM((hkv, g, 1), jnp.float32),
+                pltpu.VMEM((hkv, g, d), jnp.float32),
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((bsz, hkv, g, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(tables, pos, q.reshape(bsz, hkv, g, d), k_cache, v_cache)
     return out.reshape(bsz, hq, d)[:, None]
+
+
+def paged_block_bytes(page: int, hkv: int, g: int, d: int, itemsize: int) -> int:
+    """VMEM bytes of one grid step of :func:`paged_decode_attention`, the
+    number ``ops.paged_attention`` and the plan compiler test against
+    ``VMEM_LIMIT_BYTES``: double-buffered K and V pages and query/output
+    blocks, plus the f32 online-softmax scratch."""
+    return (2 * 2 * page * hkv * d * itemsize + 2 * 2 * hkv * g * d * itemsize
+            + hkv * g * (d + 2) * 4)
 
 
 def paged_attention_xla(

@@ -19,9 +19,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.config import VMEM_LIMIT_BYTES
+
 
 def _ssd_kernel(
-    x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, y_ref, state_ref,
+    x_ref, dt_ref, dtr_ref, a_ref, b_ref, c_ref, d_ref, y_ref, state_ref,
     *, chunk: int, n_chunks: int,
 ):
     ci = pl.program_id(2)
@@ -30,23 +32,29 @@ def _ssd_kernel(
     def _init():
         state_ref[...] = jnp.zeros_like(state_ref)
 
+    h = pl.program_id(1)
     x = x_ref[0, 0, 0].astype(jnp.float32)     # (chunk, P)
     dt = dt_ref[0, 0, 0].astype(jnp.float32)   # (chunk, 1)
-    a = a_ref[0]                               # (1,) decay rate (negative)
+    dt_row = dtr_ref[0, 0, 0].astype(jnp.float32)  # (1, chunk): same values
+    a = a_ref[h]                               # scalar decay rate (negative)
     bm = b_ref[0, 0].astype(jnp.float32)       # (chunk, N)
     cm = c_ref[0, 0].astype(jnp.float32)       # (chunk, N)
-    d = d_ref[0]                               # (1,)
+    d = d_ref[h]                               # scalar skip weight
 
+    # inclusive prefix sums of dt*a as masked reductions (Mosaic has no
+    # cumsum): the column form reduces the row copy over lanes, the row
+    # form reduces the column copy over sublanes, so nothing is transposed
     aseg = dt * a                              # (chunk, 1)
-    cum = jnp.cumsum(aseg, axis=0)             # (chunk, 1) inclusive
-    total = cum[chunk - 1, 0]
+    aseg_row = dt_row * a                      # (1, chunk)
+    r = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    c = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    tri = r >= c
+    cum = jnp.sum(jnp.where(tri, aseg_row, 0.0), axis=1, keepdims=True)  # (chunk, 1)
+    cum_row = jnp.sum(jnp.where(r <= c, aseg, 0.0), axis=0, keepdims=True)
+    total = jnp.sum(aseg, axis=0, keepdims=True)  # (1, 1)
 
     # intra-chunk: L[i,j] = exp(cum_i - cum_j) * [i >= j]
-    li = cum - cum.reshape(1, chunk)           # (chunk, chunk)
-    tri = (
-        jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
-        >= jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    )
+    li = cum - cum_row                         # (chunk, chunk)
     lmat = jnp.exp(jnp.where(tri, li, -1e30))  # mask before exp (overflow)
     scores = jnp.dot(cm, bm.T, preferred_element_type=jnp.float32)
     w = scores * lmat                          # (chunk, chunk)
@@ -85,6 +93,7 @@ def ssd_scan(
     # layouts: (B, H, nc, chunk, *)
     xr = x.transpose(0, 2, 1, 3).reshape(B, H, nc, chunk, P)
     dtr = dt.transpose(0, 2, 1).reshape(B, H, nc, chunk, 1)
+    dtr_row = dtr.reshape(B, H, nc, 1, chunk)
     br = b_mat.reshape(B, nc, chunk, N)
     cr = c_mat.reshape(B, nc, chunk, N)
 
@@ -94,14 +103,18 @@ def ssd_scan(
         in_specs=[
             pl.BlockSpec((1, 1, 1, chunk, P), lambda b, h, c: (b, h, c, 0, 0)),
             pl.BlockSpec((1, 1, 1, chunk, 1), lambda b, h, c: (b, h, c, 0, 0)),
-            pl.BlockSpec((1,), lambda b, h, c: (h,)),
+            pl.BlockSpec((1, 1, 1, 1, chunk), lambda b, h, c: (b, h, c, 0, 0)),
+            # per-head scalars: the whole (H,) vectors sit in SMEM (a
+            # size-1 rank-1 VMEM block is not tile-aligned)
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, 1, chunk, N), lambda b, h, c: (b, c, 0, 0)),
             pl.BlockSpec((1, 1, chunk, N), lambda b, h, c: (b, c, 0, 0)),
-            pl.BlockSpec((1,), lambda b, h, c: (h,)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=pl.BlockSpec((1, 1, 1, chunk, P), lambda b, h, c: (b, h, c, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H, nc, chunk, P), x.dtype),
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
-    )(xr, dtr, a.astype(jnp.float32), br, cr, d.astype(jnp.float32))
+    )(xr, dtr, dtr_row, a.astype(jnp.float32), br, cr, d.astype(jnp.float32))
     return out.reshape(B, H, S, P).transpose(0, 2, 1, 3)

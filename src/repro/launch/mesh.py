@@ -10,12 +10,13 @@ from __future__ import annotations
 import jax
 
 from repro.config import MULTI_POD_MESH, SINGLE_POD_MESH, MeshConfig
+from repro.core.sharding import make_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def mesh_cfg_for(*, multi_pod: bool = False) -> MeshConfig:
@@ -25,4 +26,4 @@ def mesh_cfg_for(*, multi_pod: bool = False) -> MeshConfig:
 def make_local_mesh():
     """Whatever devices exist locally (smoke tests / examples)."""
     n = len(jax.devices())
-    return jax.make_mesh((n,), ("data",))
+    return make_mesh((n,), ("data",))

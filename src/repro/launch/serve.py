@@ -55,6 +55,7 @@ from __future__ import annotations
 import argparse
 import random
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config
 from repro.runtime.engine_config import EngineConfig
 from repro.runtime.scheduler import simulate_arrivals
@@ -307,6 +308,7 @@ def main():
                          "buffers) instead of serving corrupt state")
     args = ap.parse_args()
 
+    enable_compile_cache()
     if args.scheduler:
         serve_scheduled(args)
     elif args.stream:

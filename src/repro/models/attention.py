@@ -34,14 +34,15 @@ def attention(
     causal: bool = True,
     window: int = 0,
     q_offset: int = 0,  # absolute position of q[0] relative to k[0]
+    partition=None,     # ShardCtx.kernel_map of a multi-device step
 ) -> jnp.ndarray:
     b, sq, hq, d = q.shape
     sk = k.shape[1]
-    if jax.default_backend() == "tpu":
+    if kops.on_tpu():
         out = kops.attention(
             q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
             v.transpose(0, 2, 1, 3), causal=causal, window=window,
-            q_offset=q_offset,
+            q_offset=q_offset, partition=partition,
         )
         return out.transpose(0, 2, 1, 3)
     big = max(sq, sk) >= BLOCKED_THRESHOLD

@@ -148,7 +148,8 @@ def attn_block_apply(
     if not _sp_attention(cfg, ctx):
         h = ctx.seq_gather(h)
     q, k, v, (kr, vr) = _qkv(cfg, p, h, positions, ctx=ctx)
-    o = ATT.attention(q, k, v, causal=causal, window=window)
+    o = ATT.attention(q, k, v, causal=causal, window=window,
+                      partition=ctx.kernel_map)
     if _sp_attention(cfg, ctx) and not (ctx.plan and ctx.plan.seq_axes):
         o = ctx.constrain_seq_model(o)
     else:
@@ -162,7 +163,7 @@ def attn_block_apply(
         if cfg.q_per_kv > 1:
             kx = jnp.repeat(kx, cfg.q_per_kv, axis=2)
             vx = jnp.repeat(vx, cfg.q_per_kv, axis=2)
-        ox = ATT.attention(qx, kx, vx, causal=False)
+        ox = ATT.attention(qx, kx, vx, causal=False, partition=ctx.kernel_map)
         x = x + jnp.einsum("bshk,hkd->bsd", ox, p["xwo"])
     h = ctx.seq_gather(rms_norm(x, p["ln2"]))
     f, aux = _ffn(cfg, p, h, ctx)
@@ -201,7 +202,8 @@ def attn_block_decode(
             # committed-slot mask == decode validity mask for both dense
             # and rotating rows (see kernels/paged_attention.py), so the
             # fused op needs pos and sc but not the window
-            o = kops.paged_attention(q, kc, vc, tables, pos, page=page, sc=sc)
+            o = kops.paged_attention(q, kc, vc, tables, pos, page=page, sc=sc,
+                                     partition=ctx.kernel_map)
         elif decode_kernel == "ref":
             o = kref.paged_decode_ref(q, kc, vc, tables, pos, page=page,
                                       sc=sc, window=window)
@@ -228,7 +230,7 @@ def attn_block_decode(
         if cfg.q_per_kv > 1:
             kx = jnp.repeat(kx, cfg.q_per_kv, axis=2)
             vx = jnp.repeat(vx, cfg.q_per_kv, axis=2)
-        ox = ATT.attention(qx, kx, vx, causal=False)
+        ox = ATT.attention(qx, kx, vx, causal=False, partition=ctx.kernel_map)
         x = x + jnp.einsum("bshk,hkd->bsd", ox, p["xwo"])
     h = rms_norm(x, p["ln2"])
     f, _ = _ffn(cfg, p, h, ctx)
@@ -448,7 +450,8 @@ def ssd_block_apply(cfg: ModelConfig, p: Dict, x: jnp.ndarray,
     nh, hd = cfg.ssm_num_heads, cfg.ssm_head_dim
     xh = xin.reshape(b, s, nh, hd)
     a = -jnp.exp(p["a_log"].astype(jnp.float32))
-    y = kops.ssd(xh, dt, a, bm, cm, p["d_skip"].astype(jnp.float32))
+    y = kops.ssd(xh, dt, a, bm, cm, p["d_skip"].astype(jnp.float32),
+                 partition=ctx.kernel_map)
     y = y.reshape(b, s, cfg.d_inner)
     y = rms_norm(y * jax.nn.silu(z.astype(jnp.float32)).astype(x.dtype), p["gate_ln"])
     out = x + ctx.ckpt_constrain(jnp.einsum("bse,ed->bsd", y, p["w_out"]))

@@ -9,13 +9,14 @@ owns logical structure (the SystemML separation of script from plan).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.sharding import PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.config import MeshConfig
 from repro.core.sharding import spec_for
@@ -31,6 +32,15 @@ from repro.core.strategies import PlanConfig
 class ShardCtx:
     plan: Optional[PlanConfig] = None
     mesh_cfg: Optional[MeshConfig] = None
+    # the concrete mesh the step runs on: constraints then name it
+    # (NamedSharding) and need no mesh context at trace time. Without it a
+    # bare PartitionSpec resolves against the caller's ``with mesh:``.
+    mesh: Optional[Mesh] = None
+
+    def _pin(self, x: jnp.ndarray, spec: P) -> jnp.ndarray:
+        if self.mesh is not None:
+            spec = NamedSharding(self.mesh, spec)
+        return lax.with_sharding_constraint(x, spec)
 
     def constrain(self, x: jnp.ndarray, axes: Tuple[Optional[str], ...],
                   kind: str = "act") -> jnp.ndarray:
@@ -39,7 +49,46 @@ class ShardCtx:
         if self.mesh_cfg.num_devices == 1:
             return x  # LOCAL plan: nothing to constrain (no mesh in context)
         spec = spec_for(tuple(x.shape), axes, self.plan, self.mesh_cfg, kind)
-        return lax.with_sharding_constraint(x, spec)
+        return self._pin(x, spec)
+
+    def kernel_map(self, fn, in_axes, out_axes):
+        """Place a Pallas kernel call on the step's mesh. Mosaic kernels
+        cannot be partitioned by the compiler, so on a multi-device mesh the
+        call runs under ``shard_map``: "batch" dims split over the plan's
+        batch axes and "heads" dims over "model" under tensor parallelism,
+        each only where every such dim divides; all else is replicated.
+        ``in_axes``: one logical-axes tuple per positional argument."""
+        if self.mesh is None or self.plan is None:
+            return fn
+        plan, mesh_cfg = self.plan, self.mesh_cfg
+
+        def run(*args):
+            dims = {"batch": set(), "heads": set()}
+            for x, axes in zip(args, in_axes):
+                for n, ax in zip(x.shape, axes):
+                    if ax in dims:
+                        dims[ax].add(n)
+            batch = plan.batch_axes
+            nb = math.prod(mesh_cfg.shape[mesh_cfg.axis_names.index(a)]
+                           for a in batch)
+            mp = mesh_cfg.model_parallelism
+            place = {
+                "batch": (batch if batch and nb > 1
+                          and all(n % nb == 0 for n in dims["batch"])
+                          else None),
+                "heads": ("model" if plan.tensor_parallel and mp > 1
+                          and all(n % mp == 0 for n in dims["heads"])
+                          else None),
+            }
+
+            def spec(axes):
+                return P(*(place.get(ax) for ax in axes))
+
+            return jax.shard_map(
+                fn, mesh=self.mesh, in_specs=tuple(spec(a) for a in in_axes),
+                out_specs=spec(out_axes), check_vma=False)(*args)
+
+        return run
 
     def ckpt_constrain(self, x: jnp.ndarray) -> jnp.ndarray:
         """Residual-checkpoint constraint: seq over 'model' when the plan
@@ -48,7 +97,7 @@ class ShardCtx:
         if self.plan is None or not self.plan.seq_shard_checkpoints:
             return x
         batch = self.plan.batch_axes or None
-        return lax.with_sharding_constraint(x, P(batch, "model", None))
+        return self._pin(x, P(batch, "model", None))
 
     def constrain_seq_model(self, x: jnp.ndarray) -> jnp.ndarray:
         """Pin dim-1 (seq) to the model axis, rest replicated-by-batch —
@@ -57,8 +106,7 @@ class ShardCtx:
         if self.plan is None or self.mesh_cfg is None or self.mesh_cfg.num_devices == 1:
             return x
         batch = self.plan.batch_axes or None
-        return lax.with_sharding_constraint(
-            x, P(*([batch, "model"] + [None] * (x.ndim - 2))))
+        return self._pin(x, P(*([batch, "model"] + [None] * (x.ndim - 2))))
 
     def seq_gather(self, x: jnp.ndarray) -> jnp.ndarray:
         """Megatron-SP region boundary: all-gather the seq dim at layer
@@ -68,8 +116,7 @@ class ShardCtx:
         if self.plan is None or not self.plan.seq_shard_checkpoints:
             return x
         batch = self.plan.batch_axes or None
-        return lax.with_sharding_constraint(
-            x, P(*([batch] + [None] * (x.ndim - 1))))
+        return self._pin(x, P(*([batch] + [None] * (x.ndim - 1))))
 
 
 NULL_CTX = ShardCtx()
@@ -105,24 +152,42 @@ class SpecBuilder:
     def axes(self):
         return {k: ax for k, (sh, ax, ini, sc, dt) in self.entries.items()}
 
-    def init(self, key):
+    def init(self, key, shardings=None):
+        """Random initial values, each leaf made in its own dtype by one
+        small jitted program, so no float32 copy of a large (layer-stacked)
+        weight is ever resident. ``shardings``: optional {name: Sharding}
+        the leaves are created under (a sharded model is never gathered
+        onto one device)."""
         out = {}
         for k, (sh, ax, ini, sc, dt) in self.entries.items():
             key, sub = jax.random.split(key)
-            if ini == "zeros":
-                out[k] = jnp.zeros(sh, dt)
-            elif ini == "ones":
-                out[k] = jnp.ones(sh, dt)
-            elif ini == "ssm_a":
-                # A_log init: log of uniform [1, 16] (mamba2 convention)
-                out[k] = jnp.log(
-                    jax.random.uniform(sub, sh, jnp.float32, 1.0, 16.0)
-                ).astype(dt)
-            else:
+            if ini == "normal":
                 fan_in = sh[-2] if len(sh) >= 2 else sh[-1]
-                s = sc if sc is not None else 1.0 / math.sqrt(max(1, fan_in))
-                out[k] = (jax.random.normal(sub, sh, jnp.float32) * s).astype(dt)
+                sc = sc if sc is not None else 1.0 / math.sqrt(max(1, fan_in))
+            make = _init_leaf_fn(sh, jnp.dtype(dt).name, ini, sc,
+                                 None if shardings is None else shardings[k])
+            out[k] = make(sub)
         return out
+
+
+@functools.lru_cache(maxsize=256)
+def _init_leaf_fn(shape, dtype: str, init: str, scale, sharding):
+    """One jitted initializer per distinct leaf signature (shared by every
+    model built in the process)."""
+
+    def make(key):
+        if init == "zeros":
+            return jnp.zeros(shape, dtype)
+        if init == "ones":
+            return jnp.ones(shape, dtype)
+        if init == "ssm_a":
+            # A_log init: log of uniform [1, 16] (mamba2 convention)
+            return jnp.log(jax.random.uniform(key, shape, jnp.float32,
+                                              1.0, 16.0)).astype(dtype)
+        return (jax.random.normal(key, shape, jnp.float32) * scale
+                ).astype(dtype)
+
+    return jax.jit(make, out_shardings=sharding)
 
 
 def merge_trees(**subtrees):

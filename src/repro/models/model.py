@@ -70,8 +70,8 @@ class Model:
     def param_axes(self):
         return self.sb.axes()
 
-    def init_params(self, key):
-        return self.sb.init(key)
+    def init_params(self, key, shardings=None):
+        return self.sb.init(key, shardings)
 
     def param_count(self) -> int:
         return sum(math.prod(s.shape) for s in self.param_specs().values())

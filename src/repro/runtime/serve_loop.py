@@ -24,12 +24,14 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.config import InputShape, MeshConfig, ModelConfig, TPU_V5E, HardwareSpec
+from repro.config import (InputShape, MeshConfig, ModelConfig, TPU_V5E,
+                          HardwareSpec, hardware_for)
 from repro.core.plan_cache import (BucketPolicy, CacheEntry, PlanCache,
                                    PlanKey)
-from repro.core.planner import PlanCompiler
-from repro.core.sharding import tree_specs
+from repro.core.planner import OVER_HBM_BUDGET, PlanCompiler
+from repro.core.sharding import make_mesh, tree_specs
 from repro.core.strategies import ExecutionPlan, PlanConfig, RuntimeStats
+from repro.kernels import ops as kops
 from repro.models.common import ShardCtx
 from repro.models.model import build_model
 from repro.runtime.engine import ServingEngine, WallClock
@@ -40,15 +42,16 @@ from repro.runtime.metrics import LatencyStats, serve_summary
 
 
 def make_decode_step(model, plan: PlanConfig, mesh_cfg: MeshConfig,
-                     page: int = 0, seq_len: int = 0):
+                     page: int = 0, seq_len: int = 0, mesh=None):
     """``page > 0`` builds the block-granular paged decode step: it takes a
     fifth argument — the (B, max_pages) page-table array — and the cache's
     attention K/V are flat per-arena slot stacks (``paged_cache_entries``).
     ``seq_len`` is the bucket context the arena is sized for (the flat
     layout no longer carries it). The physical decode-attention operator
     (paged Pallas kernel / jnp gather / ref oracle) is read off the plan:
-    the compiler chose it per bucket, so the jitted step bakes it in."""
-    ctx = ShardCtx(plan, mesh_cfg)
+    the compiler chose it per bucket, so the jitted step bakes it in.
+    ``mesh``: the concrete mesh of a multi-device server."""
+    ctx = ShardCtx(plan, mesh_cfg, mesh)
     kernel = plan.decode_kernel if plan.decode_kernel in ("paged", "ref") \
         else "gather"
 
@@ -66,8 +69,8 @@ def make_decode_step(model, plan: PlanConfig, mesh_cfg: MeshConfig,
     return decode_step
 
 
-def make_prefill(model, plan: PlanConfig, mesh_cfg: MeshConfig):
-    ctx = ShardCtx(plan, mesh_cfg)
+def make_prefill(model, plan: PlanConfig, mesh_cfg: MeshConfig, mesh=None):
+    ctx = ShardCtx(plan, mesh_cfg, mesh)
 
     def prefill(params, batch):
         extra = {k: v for k, v in batch.items()
@@ -140,6 +143,19 @@ class ServeRequest:
     rid: int = field(default_factory=lambda: next(_NEXT_RID))
 
 
+class PlanOverBudgetError(RuntimeError):
+    """A server on a TPU was handed a plan that does not fit its HBM."""
+
+
+def device_hardware() -> HardwareSpec:
+    """The chip the server runs on: on a TPU backend, the published figures
+    for ``jax.devices()[0].device_kind`` (an unknown kind raises); off TPU,
+    ``TPU_V5E`` stays the analytic target the CPU tests plan for."""
+    if not kops.on_tpu():
+        return TPU_V5E
+    return hardware_for(jax.devices()[0].device_kind)
+
+
 def _tree_bytes(tree) -> float:
     return float(sum(x.nbytes for x in jax.tree.leaves(tree)  # lint: allow-tracer-host-sync (host-side sizing)
                      if hasattr(x, "nbytes")))
@@ -172,7 +188,7 @@ class PlanServer:
         mesh_cfg: Optional[MeshConfig] = None,
         dtype=_UNSET,
         *,
-        hw: HardwareSpec = TPU_V5E,
+        hw: Optional[HardwareSpec] = None,
         config: Optional[EngineConfig] = None,
         enable_cache: bool = _UNSET,
         capacity: int = _UNSET,
@@ -199,11 +215,13 @@ class PlanServer:
         self.cfg = cfg
         self.mesh_cfg = mesh_cfg or MeshConfig(
             shape=(len(jax.devices()),), axis_names=("data",))
+        # several devices: a real mesh; params are created under it and
+        # every step constrains its tensors on it
+        self.mesh = (make_mesh(self.mesh_cfg.shape, self.mesh_cfg.axis_names)
+                     if self.mesh_cfg.num_devices > 1 else None)
         self.dtype = c.jnp_dtype()
         self.dtype_name = c.dtype
         self.model = build_model(cfg, dtype=self.dtype)
-        self.params = self.model.init_params(jax.random.PRNGKey(c.seed))
-        self._params_bytes = _tree_bytes(self.params)
         # block-granular paged arenas (0 = row-granular PR-3 behaviour):
         # rows commit pages, not bucket-shaped sequence slack
         self.page_size = max(0, int(c.page_size))  # lint: allow-tracer-host-sync (config int)
@@ -211,10 +229,15 @@ class PlanServer:
         # with ``pool_arenas`` concurrent bucket arenas; the pool's live
         # bytes are checked against them at observe() time
         self.pool_arenas = max(1, c.pool_arenas)
-        self.compiler = PlanCompiler(hw, cache_pool_arenas=self.pool_arenas,
+        self.policy = policy
+        self.hw = hw if hw is not None else device_hardware()
+        self.compiler = PlanCompiler(self.hw, cache_pool_arenas=self.pool_arenas,
                                      cache_page_size=self.page_size,
                                      decode_kernel=c.decode_kernel,
                                      donate_cache=c.donate)
+        self.params = self.model.init_params(jax.random.PRNGKey(c.seed),
+                                             self._param_shardings(c))
+        self._params_bytes = _tree_bytes(self.params)
         self.pool = KVCachePool(self.model, max_arenas=c.pool_max_arenas,
                                 max_bytes=c.pool_max_bytes,
                                 page_size=self.page_size)
@@ -223,7 +246,6 @@ class PlanServer:
         self.latency = LatencyStats()
         self.enable_cache = c.enable_cache
         self.recompile_margin = c.recompile_margin
-        self.policy = policy
         # prefill=True: handle() runs the cached-prefill prompt pass, hands
         # the populated cache rows to decode (no zero-cache restart), and
         # the prefill-produced first token opens the output; False keeps the
@@ -231,15 +253,44 @@ class PlanServer:
         self.prefill = c.prefill
         self._engine: Optional[ServingEngine] = None
 
+    def _param_shardings(self, c: EngineConfig):
+        """Where the params live on a multi-device mesh (None: one device).
+        Params are shared by every bucket's plan, so they follow the plan
+        for the widest decode group at the smallest context; a bucket whose
+        plan lays them out differently still runs correctly, its
+        constraints resharding activations around them."""
+        if self.mesh is None:
+            return None
+        shape = InputShape("serve_params", self.policy.min_seq,
+                           c.max_group_batch, "decode")
+        plan = self.compiler.compile(self.cfg, shape, self.mesh_cfg,
+                                     dtype=self.dtype_name)
+        self._check_budget(plan)
+        specs = tree_specs(self.model.param_specs(), self.model.param_axes(),
+                           plan.config, self.mesh_cfg, "param")
+        return {k: NamedSharding(self.mesh, sp) for k, sp in specs.items()}
+
+    def _check_budget(self, plan: ExecutionPlan) -> None:
+        """On a real chip, refuse a plan the compiler could only emit with
+        its over-budget warning: it would not fit the device's HBM."""
+        if OVER_HBM_BUDGET in plan.config.notes and kops.on_tpu():
+            raise PlanOverBudgetError(
+                f"{self.cfg.name} {plan.shape.kind} "
+                f"{plan.shape.global_batch}x{plan.shape.seq_len} on "
+                f"{self.hw.name}: {OVER_HBM_BUDGET} "
+                f"({plan.memory.total / 1e9:.2f} GB per chip estimated)")
+
     # ------------------------------------------------------------------
     def _build_step(self, plan: ExecutionPlan):
+        self._check_budget(plan)
         if plan.shape.kind == "prefill":
             # nothing safe to donate: the prompt pass has no cache input
             # and params are shared by every plan
-            return jax.jit(make_prefill(self.model, plan.config, self.mesh_cfg))
+            return jax.jit(make_prefill(self.model, plan.config,
+                                        self.mesh_cfg, self.mesh))
         step = make_decode_step(self.model, plan.config, self.mesh_cfg,
                                 page=self.page_size,
-                                seq_len=plan.shape.seq_len)
+                                seq_len=plan.shape.seq_len, mesh=self.mesh)
         if plan.config.donate_cache:
             # donate the cache pytree (positional arg 1): XLA aliases each
             # cache output onto its input buffer, so the slot stacks and

@@ -22,6 +22,7 @@ def _fresh_legacy_kwarg_warnings():
 
 MULTIDEV_PRELUDE = """
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"  # host devices only; never the chip
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count={n}"
 import sys
 sys.path.insert(0, {src!r})

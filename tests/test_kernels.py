@@ -54,6 +54,7 @@ def test_conv2d_im2col(n, c, h, w, f, kern, stride, pad):
     (1, 2, 1, 1, 96, 32, True, 0),     # decode: single query
     (1, 2, 1, 100, 100, 32, True, 0),  # non-tile-aligned
     (2, 4, 1, 64, 64, 32, True, 0),    # MQA
+    (1, 4, 2, 5, 5, 32, True, 0),      # short prompt: one padded 8-row block
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_flash_attention(b, hq, hkv, sq, sk, d, causal, window, dtype):
@@ -75,6 +76,7 @@ def test_flash_attention(b, hq, hkv, sq, sk, d, causal, window, dtype):
     (1, 32, 2, 16, 8, 8),
     (2, 128, 4, 8, 32, 32),
     (1, 64, 1, 32, 64, 16),
+    (1, 128, 2, 64, 128, 64),   # mamba2 head/state widths, published chunk
 ])
 def test_ssd_scan(b, s, h, p, n, chunk):
     ks = jax.random.split(KEY, 5)
@@ -118,3 +120,17 @@ def test_ops_dispatch_fallback():
         np.testing.assert_allclose(ops.matmul(a, b), want, rtol=1e-5)
     finally:
         ops.BACKEND = old
+
+
+def test_ops_raises_past_vmem_budget_on_tpu(monkeypatch):
+    """On a TPU a kernel whose block set does not fit the scoped VMEM
+    limit raises; it never falls back to XLA. Off TPU a forced pallas
+    still falls back (interpret mode has no VMEM)."""
+    q = jnp.zeros((1, 1, 16, 32), jnp.float32)
+    big = 64 * 1024                     # a (64k x 64k) f32 score block
+    monkeypatch.setattr(ops, "BACKEND", "pallas")
+    want = ref.attention_ref(q, q, q)
+    np.testing.assert_allclose(ops.attention(q, q, q, bq=big, bk=big), want)
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    with pytest.raises(ops.VmemBudgetError, match="flash_attention"):
+        ops.attention(q, q, q, bq=big, bk=big)

@@ -10,9 +10,10 @@ def test_parfor_remote_equals_local_and_no_shuffle():
     out = run_multidev("""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
+from repro.core.sharding import make_mesh
 from repro.core.parfor import parfor, choose_parfor_plan, count_collectives
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 w = jax.random.normal(jax.random.PRNGKey(0), (16, 8))
 
 def score(rows):
@@ -50,7 +51,8 @@ def test_parfor_optimizer_chooses_local_for_small_input():
     out = run_multidev("""
 import jax
 from repro.core.parfor import choose_parfor_plan
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+from repro.core.sharding import make_mesh
+mesh = make_mesh((4, 2), ("data", "model"))
 assert choose_parfor_plan(2, mesh) == "local"      # too few rows
 assert choose_parfor_plan(3, mesh) == "local"      # indivisible
 assert choose_parfor_plan(64, mesh) == "remote"
@@ -66,6 +68,7 @@ def test_sharded_train_step_multidev():
     out = run_multidev("""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
+from repro.core.sharding import make_mesh
 from repro.config import MeshConfig, InputShape, TrainConfig
 from repro.configs import get_config
 from repro.core.planner import compile_plan
@@ -76,7 +79,7 @@ from repro.runtime.train_loop import (make_train_step, init_opt_state,
 from repro.data import make_batch
 
 mesh_cfg = MeshConfig(shape=(4, 2), axis_names=("data", "model"))
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 cfg = get_config("yi-6b-smoke")
 shape = InputShape("tiny", 32, 8, "train")
 train = TrainConfig(optimizer="adam", learning_rate=1e-2, force_strategy="fsdp_tensor_parallel")

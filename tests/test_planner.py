@@ -20,6 +20,7 @@ from repro.core.memory import estimate_memory
 from repro.core.planner import PlanCompiler, compile_plan
 from repro.core.sharding import spec_for
 from repro.core.strategies import PlanConfig, Strategy
+from repro.kernels import ops
 
 
 def test_single_device_gets_local_plan():
@@ -145,3 +146,29 @@ def test_spec_for_valid(shape, tp, fsdp):
         for ax in axes:
             size *= dict(zip(SINGLE_POD_MESH.axis_names, SINGLE_POD_MESH.shape))[ax]
         assert shape[i] % size == 0, (shape, spec)
+
+
+def test_server_on_tpu_refuses_over_budget_plan(monkeypatch):
+    """The planner still emits an over-budget plan, with its warning note,
+    for the analytic dry-run; a server on a TPU refuses to run it."""
+    from repro.core.planner import OVER_HBM_BUDGET
+    from repro.runtime import serve_loop
+    from repro.runtime.engine_config import EngineConfig
+
+    tiny = HardwareSpec(hbm_bytes=1024)
+    srv = EngineConfig().build_server(get_config("yi-6b-smoke"), hw=tiny)
+    assert OVER_HBM_BUDGET in srv.decode_entry(1, 32).plan.config.notes
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    with pytest.raises(serve_loop.PlanOverBudgetError, match="HBM budget"):
+        srv.decode_entry(2, 64)
+
+
+def test_server_hardware_comes_from_device_kind(monkeypatch):
+    from repro.config import TPU_SPECS, hardware_for
+    from repro.runtime import serve_loop
+
+    assert serve_loop.device_hardware() is TPU_V5E       # off TPU
+    assert hardware_for("TPU v5 lite") is TPU_SPECS["TPU v5 lite"]
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    with pytest.raises(ValueError, match="no HardwareSpec"):
+        serve_loop.device_hardware()                     # kind "cpu"

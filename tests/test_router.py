@@ -179,17 +179,22 @@ def test_placement_determinism_property(shapes):
 # ---------------------------------------------------------------------------
 
 
+@pytest.fixture(scope="module")
+def warm_fleet():
+    """One two-replica fleet shared across the property's examples, so the
+    plan caches fill once instead of once per example."""
+    return EngineRouter([ECFG.build_server(CFG) for _ in range(2)],
+                        config=ECFG)
+
+
 @settings(max_examples=5, deadline=None)
 @given(st.lists(st.sampled_from([(1, 40, 4), (1, 100, 4), (2, 44, 4)]),
                 min_size=3, max_size=7))
-def test_starvation_freedom_property(shapes, _fleet=[]):
+def test_starvation_freedom_property(warm_fleet, shapes):
     """At every tick boundary (after the tick's rebalance), no replica
     sits idle while another replica still holds queued work — placement
     prefers idle replicas and work stealing migrates leftover backlog."""
-    if not _fleet:  # warm fleet shared across examples (plan caches fill)
-        _fleet.append(EngineRouter(
-            [ECFG.build_server(CFG) for _ in range(2)], config=ECFG))
-    router = _fleet[0]
+    router = warm_fleet
     for b, c, n in shapes:
         router.submit(ServeRequest(b, c, n))
     while not router.idle:
