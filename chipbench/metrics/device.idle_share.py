@@ -1,0 +1,7 @@
+"""Share of the traced window in which no operation ran on the device."""
+
+
+def read(run):
+    if run.red is None or run.red.window_s <= 0:
+        return None
+    return 100.0 * (1.0 - run.red.busy_s / run.red.window_s)
