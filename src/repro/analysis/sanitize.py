@@ -142,7 +142,8 @@ def recount_live_bytes(pool) -> float:
 
 def check_pool(pool, where: str = "pool") -> List[Finding]:
     """Pool-level invariants: every arena's structure, lease/free-list
-    disjointness, and ``live_bytes()`` vs. a from-scratch recount."""
+    disjointness, ``live_bytes()`` vs. a from-scratch recount, and the
+    free-arena gauge vs. the free list."""
     out: List[Finding] = []
     for i, a in enumerate(pool._leased):
         out.extend(check_arena(a, f"{where}.leased[{i}]"))
@@ -168,6 +169,11 @@ def check_pool(pool, where: str = "pool") -> List[Finding]:
         out.append(_f("live-bytes-drift", where,
                       f"live_bytes()={live:.0f} but recount={recount:.0f}",
                       live=live, recount=recount))
+    pooled = sum(a.nbytes for a in pool._pooled)
+    if abs(pool.metrics.pooled_bytes - pooled) > max(1.0, 1e-6 * pooled):
+        out.append(_f("pooled-bytes-drift", where,
+                      f"metrics.pooled_bytes={pool.metrics.pooled_bytes:.0f} "
+                      f"but free arenas hold {pooled:.0f}"))
     if pool.max_bytes and live - pool.max_bytes > 1.0:
         out.append(_f("byte-budget-breach", where,
                       f"live {live:.0f} > budget {pool.max_bytes:.0f}"))

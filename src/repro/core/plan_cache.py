@@ -37,6 +37,8 @@ from collections import OrderedDict
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, Iterable, Optional, Tuple
 
+from jax.profiler import TraceAnnotation
+
 from repro.config import MeshConfig, ModelConfig, InputShape, TrainConfig
 from repro.core.strategies import ExecutionPlan, RuntimeStats
 
@@ -290,16 +292,18 @@ class PlanCache:
                 # possibly traced) plan — reuse it, don't clobber it
                 self._entries.move_to_end(new_key)
                 return existing, reasons
-        new_plan = compiler.recompile(entry.plan, stats, train)
-        # same bucket + same layout decisions: only the statistics were
-        # corrected, so the already-traced executable stays valid
-        same_config = (new_key == key
-                       and new_plan.config.replace(notes=())
-                       == entry.plan.config.replace(notes=()))
-        if same_config:
-            step_fn = entry.step_fn
-        else:
-            step_fn = build_step(new_plan) if build_step else None
+        # the serving engine's plan phase, as on a cache miss
+        with TraceAnnotation("engine.plan"):
+            new_plan = compiler.recompile(entry.plan, stats, train)
+            # same bucket + same layout decisions: only the statistics were
+            # corrected, so the already-traced executable stays valid
+            same_config = (new_key == key
+                           and new_plan.config.replace(notes=())
+                           == entry.plan.config.replace(notes=()))
+            if same_config:
+                step_fn = entry.step_fn
+            else:
+                step_fn = build_step(new_plan) if build_step else None
         new_entry = CacheEntry(key=new_key, plan=new_plan, step_fn=step_fn)
         self.put(new_key, new_entry)
         self.metrics.recompiles += 1

@@ -25,6 +25,14 @@ The engine is re-entrant and tick-driven:
   span reservation are released the same tick, so early exits immediately
   become mid-decode join capacity and byte-budget headroom.
 
+Every tick phase runs under a fixed-name ``jax.profiler.TraceAnnotation``
+(``engine.tick``, ``engine.joins``, ``engine.form``, ``engine.admit``,
+``engine.decode``, ``engine.wait``, ``engine.emit``, ``engine.retire``;
+the server adds ``engine.prefill`` and ``engine.plan``), so a profiler trace
+puts every device-idle gap down to a phase of the host's tick. The spans
+cost well under a microsecond each when no profiler runs, and are emitted
+once per phase per tick or per group, never per request or per token.
+
 Time is injectable (:class:`Clock` protocol): :class:`VirtualClock` skips
 idle gaps for simulated benches, :class:`WallClock` serves online traffic,
 :class:`ReplicaClock` accrues only the compute executed between its
@@ -48,6 +56,7 @@ from typing import (TYPE_CHECKING, Any, Deque, Dict, Iterable, Iterator,
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.analysis.sanitize import assert_engine
 from repro.config import InputShape
@@ -627,21 +636,24 @@ class ServingEngine:
         """Advance the engine by one tick: mid-decode joins, at most one
         new group, then one decode step for every active group. Returns the
         events emitted during this tick."""
+        self.metrics.ticks += 1
         self._tick_sink = []
         try:
-            if self.join_mid_decode:
-                for group in self.active:
-                    self._phase_joins(group)
-            self._phase_form()
-            self.metrics.observe_resident(
-                sum(1 for g in self.active for m in g.members if not m.done))
-            for group in list(self.active):
-                if not group.done:
-                    self._phase_tick(group)
-                if group.done:
-                    self._retire_group(group)
-                    self.active.remove(group)
-            self._sanitize()
+            with TraceAnnotation("engine.tick"):
+                if self.join_mid_decode:
+                    for group in self.active:
+                        self._phase_joins(group)
+                self._phase_form()
+                self.metrics.observe_resident(
+                    sum(1 for g in self.active for m in g.members
+                        if not m.done))
+                for group in list(self.active):
+                    if not group.done:
+                        self._phase_tick(group)
+                    if group.done:
+                        self._retire_group(group)
+                        self.active.remove(group)
+                self._sanitize()
             return self._tick_sink
         finally:
             self._tick_sink = None
@@ -767,6 +779,9 @@ class ServingEngine:
                     if m.qr.rid == handle.rid and not m.done:
                         m.done = True
                         m.finish_reason = "withdrawn"
+                        # the new replica observes the whole wait again
+                        self.metrics.queue_latency.samples.remove(
+                            m.qr.queue_s)
                         self.server.pool.free_rows(group.arena, m.rows,
                                                    early=True)
                         if group.done:
@@ -875,77 +890,87 @@ class ServingEngine:
         rows through the pool's one admission helper, prefill them as one
         batch (engine ``prefill`` mode permitting), and seat them at their
         own positions. Used both at group start (join_step 0) and for
-        mid-decode joins."""
-        srv = self.server
-        handoff = self.prefill and srv.model.supports_handoff
-        total_batch = sum(qr.req.batch for qr in queued)
-        span = max(srv.request_span(qr.req) for qr in queued)
-        # one admission sequence for every caller (rows + page commitment):
-        # PR-4's recycled-arena zero= leak came from this drifting between
-        # the sequential and scheduled paths
-        rows_per_member = [
-            srv.pool.admit_request_rows(
-                group.arena, qr.req.batch,
-                prompt=qr.req.context if handoff else 0,
-                span=srv.request_span(qr.req), eager=self.eager_pages,
-                where="_admit_members")
-            for qr in queued]
-        rows_flat = [r for rows in rows_per_member for r in rows]
-        rows_a = jnp.asarray(rows_flat, jnp.int32)
+        mid-decode joins. ``now`` is the admission time (each member's
+        ``start_s``); the prefill token is stamped when prefill has
+        finished."""
+        with TraceAnnotation("engine.admit"):
+            srv = self.server
+            handoff = self.prefill and srv.model.supports_handoff
+            total_batch = sum(qr.req.batch for qr in queued)
+            span = max(srv.request_span(qr.req) for qr in queued)
+            # one admission sequence for every caller (rows + page commitment):
+            # PR-4's recycled-arena zero= leak came from this drifting between
+            # the sequential and scheduled paths
+            rows_per_member = [
+                srv.pool.admit_request_rows(
+                    group.arena, qr.req.batch,
+                    prompt=qr.req.context if handoff else 0,
+                    span=srv.request_span(qr.req), eager=self.eager_pages,
+                    where="_admit_members")
+                for qr in queued]
+            rows_flat = [r for rows in rows_per_member for r in rows]
+            rows_a = jnp.asarray(rows_flat, jnp.int32)
 
-        lengths_rows = []
-        for qr in queued:
-            qr.start_s = now
-            # once admitted (group start or join), a page denial is history
-            self._page_denied_rids.discard(qr.rid)
-            handle = self.handles.get(qr.rid)
-            if handle is not None:
-                handle.state = "active"
-            lengths_rows += [qr.req.context] * qr.req.batch
+            lengths_rows = []
+            for qr in queued:
+                qr.start_s = now
+                self.metrics.observe_admission(qr.queue_s)
+                # once admitted (group start or join), a page denial is history
+                self._page_denied_rids.discard(qr.rid)
+                handle = self.handles.get(qr.rid)
+                if handle is not None:
+                    handle.state = "active"
+                lengths_rows += [qr.req.context] * qr.req.batch
 
-        first, pkv = None, None
-        if self.prefill:
-            entry = srv.prefill_entry(total_batch, span)
-            pb = entry.key.batch_bucket
-            lengths = jnp.asarray(
-                lengths_rows + [1] * (pb - len(lengths_rows)), jnp.int32)
-            logits, pkv = srv.run_prefill(entry, lengths=lengths)
-            first = jnp.argmax(logits, axis=-1).astype(jnp.int32)[:, None]
-        if pkv is not None:
-            srv.pool.write_rows(group.arena, rows_flat, pkv,
-                                src_rows=range(len(rows_flat)))
-            pos_rows = lengths_rows
-        else:  # no handoff (or no prefill): rows decode from zero state —
-            # clear any state a prior tenant of these rows/pages left behind
-            # (mid-decode joiners can inherit rows a completed member freed)
-            if join_step > 0:
-                srv.pool.zero_rows(group.arena, rows_flat)
-            pos_rows = [0] * len(rows_flat)
-        group.pos = group.pos.at[rows_a].set(jnp.asarray(pos_rows, jnp.int32))
-        seed = (first[: len(rows_flat)] if first is not None
-                else jnp.ones((len(rows_flat), 1), jnp.int32))
-        group.toks = group.toks.at[rows_a].set(seed)
+            first, pkv = None, None
+            t_first = now
+            if self.prefill:
+                entry = srv.prefill_entry(total_batch, span)
+                pb = entry.key.batch_bucket
+                lengths = jnp.asarray(
+                    lengths_rows + [1] * (pb - len(lengths_rows)), jnp.int32)
+                self.metrics.observe_prefill(sum(lengths_rows),
+                                             pb * entry.key.seq_bucket)
+                logits, pkv = srv.run_prefill(entry, lengths=lengths)
+                # run_prefill waits for the program: the first token exists now
+                t_first = self.clock.now()
+                first = jnp.argmax(logits, axis=-1).astype(jnp.int32)[:, None]
+            if pkv is not None:
+                srv.pool.write_rows(group.arena, rows_flat, pkv,
+                                    src_rows=range(len(rows_flat)))
+                pos_rows = lengths_rows
+            else:  # no handoff (or no prefill): rows decode from zero state —
+                # clear any state a prior tenant of these rows/pages left behind
+                # (mid-decode joiners can inherit rows a completed member freed)
+                if join_step > 0:
+                    srv.pool.zero_rows(group.arena, rows_flat)
+                pos_rows = [0] * len(rows_flat)
+            group.pos = group.pos.at[rows_a].set(jnp.asarray(pos_rows, jnp.int32))
+            seed = (first[: len(rows_flat)] if first is not None
+                    else jnp.ones((len(rows_flat), 1), jnp.int32))
+            group.toks = group.toks.at[rows_a].set(seed)
 
-        members = []
-        group.peak_rows = max(group.peak_rows, group.arena.rows_used)
-        row_i = 0
-        for qr, rows in zip(queued, rows_per_member):
-            m = _Member(qr=qr, rows=rows,
-                        rows_a=jnp.asarray(rows, jnp.int32),
-                        join_step=join_step,
-                        base_pos=qr.req.context if pkv is not None else 0)
-            row_i += qr.req.batch
-            members.append(m)
-            group.members.append(m)
-            if self.prefill and self.count_first:
-                # the prefill token already is token #1: it is emitted at
-                # admission (this is the time-to-first-token moment), and a
-                # 1-token request completes before any decode step
-                tok = seed[row_i - qr.req.batch: row_i]
-                reason = self._register_token(m, tok, now, join_step)
-                if reason is not None or m.emitted >= qr.req.new_tokens:
-                    self._complete(m, group, now, reason or "length")
-        return members
+            members = []
+            group.peak_rows = max(group.peak_rows, group.arena.rows_used)
+            row_i = 0
+            for qr, rows in zip(queued, rows_per_member):
+                m = _Member(qr=qr, rows=rows,
+                            rows_a=jnp.asarray(rows, jnp.int32),
+                            join_step=join_step,
+                            base_pos=qr.req.context if pkv is not None else 0)
+                row_i += qr.req.batch
+                members.append(m)
+                group.members.append(m)
+                if self.prefill and self.count_first:
+                    # the prefill token already is token #1: it is emitted at
+                    # admission, stamped when prefill finished (the
+                    # time-to-first-token moment), and a 1-token request
+                    # completes before any decode step
+                    tok = seed[row_i - qr.req.batch: row_i]
+                    reason = self._register_token(m, tok, t_first, join_step)
+                    if reason is not None or m.emitted >= qr.req.new_tokens:
+                        self._complete(m, group, t_first, reason or "length")
+            return members
 
     def _form_group(self, queued: List[QueuedRequest],
                     now: float) -> Optional[_Group]:
@@ -991,55 +1016,57 @@ class ServingEngine:
         head-of-line request could not use anyway — its own group still
         forms through ``next_group`` as soon as the pool can lease an
         arena."""
-        srv = self.server
-        arena = group.arena
-        free = arena.rows_free
-        if not free:
-            return
-        fits = None
-        if srv.pool.paged:
-            state = {"pages": arena.allocator.available if arena.n_pages
-                     else None,
-                     "bytes": srv.pool.bytes_room()}
+        with TraceAnnotation("engine.joins"):
+            srv = self.server
+            arena = group.arena
+            free = arena.rows_free
+            if not free:
+                return
+            fits = None
+            if srv.pool.paged:
+                state = {"pages": arena.allocator.available if arena.n_pages
+                         else None,
+                         "bytes": srv.pool.bytes_room()}
 
-            def fits(qr):
-                span = srv.request_span(qr.req)
-                pages = arena.span_pages(span) * qr.req.batch
-                nbytes = srv.pool.member_bytes(arena.seq, qr.req.batch, span)
-                if (state["pages"] is not None and pages > state["pages"]) \
-                        or nbytes > state["bytes"]:
-                    # count each backpressured *request* once, not once per
-                    # tick it stays refused
-                    if qr.rid not in self._page_denied_rids:
-                        self._page_denied_rids.add(qr.rid)
-                        srv.pool.metrics.pages_denied += 1
-                    return False
-                if state["pages"] is not None:
-                    state["pages"] -= pages
-                state["bytes"] -= nbytes
-                self._page_denied_rids.discard(qr.rid)
-                return True
+                def fits(qr):
+                    span = srv.request_span(qr.req)
+                    pages = arena.span_pages(span) * qr.req.batch
+                    nbytes = srv.pool.member_bytes(arena.seq, qr.req.batch, span)
+                    if (state["pages"] is not None and pages > state["pages"]) \
+                            or nbytes > state["bytes"]:
+                        # count each backpressured *request* once, not once per
+                        # tick it stays refused
+                        if qr.rid not in self._page_denied_rids:
+                            self._page_denied_rids.add(qr.rid)
+                            srv.pool.metrics.pages_denied += 1
+                        return False
+                    if state["pages"] is not None:
+                        state["pages"] -= pages
+                    state["bytes"] -= nbytes
+                    self._page_denied_rids.discard(qr.rid)
+                    return True
 
-        queued = self.queue.take_joinable(group.seq_bucket, free, fits=fits)
-        if not queued:
-            return
-        members = self._admit_members(group, queued, group.steps_done,
-                                      self.clock.now())
-        self.metrics.observe_joins([m.req.batch for m in members])
+            queued = self.queue.take_joinable(group.seq_bucket, free, fits=fits)
+            if not queued:
+                return
+            members = self._admit_members(group, queued, group.steps_done,
+                                          self.clock.now())
+            self.metrics.observe_joins([m.req.batch for m in members])
 
     def _phase_form(self) -> None:
         """Coalesce + admit at most one new group (pool permitting)."""
-        if not len(self.queue):
-            return
-        queued = self.queue.next_group()
-        if not queued:
-            return
-        group = self._form_group(queued, self.clock.now())
-        if group is None:
-            # pool budget exhausted: requests wait (or join)
-            self.queue.requeue_front(queued)
-        else:
-            self.active.append(group)
+        with TraceAnnotation("engine.form"):
+            if not len(self.queue):
+                return
+            queued = self.queue.next_group()
+            if not queued:
+                return
+            group = self._form_group(queued, self.clock.now())
+            if group is None:
+                # pool budget exhausted: requests wait (or join)
+                self.queue.requeue_front(queued)
+            else:
+                self.active.append(group)
 
     def _phase_tick(self, group: _Group) -> None:
         """One decode step for the group; emit each live member's token.
@@ -1051,50 +1078,55 @@ class ServingEngine:
         the ``use-after-donation`` lint rule enforces exactly this shape.
         """
         srv = self.server
-        if srv.pool.paged:
-            # grant the page covering each live row's next write position
-            # (on-demand paging: drawn from the admission-time reservation,
-            # so this can never fail mid-decode)
-            for m in group.members:
-                if not m.done:
-                    wpos = m.base_pos + (group.steps_done - m.join_step)
-                    srv.pool.ensure_decode_slots(group.arena, m.rows, wpos)
-            tables = group.arena.tables
-            cache_in = group.arena.relinquish()
-            logits, cache_out = group.entry.step_fn(
-                srv.params, cache_in, group.toks, group.pos, tables)
-        else:
-            cache_in = group.arena.relinquish()
-            logits, cache_out = group.entry.step_fn(
-                srv.params, cache_in, group.toks, group.pos)
-        # whether the step actually consumed its cache input (donation
-        # aliased the buffers): host-side flag check, no device sync —
-        # feeds the observed live-bytes watermark at group retire
-        group.cache_donated = all(  # metadata probe, never touches buffers
-            x.is_deleted() for x in jax.tree.leaves(cache_in)  # lint: allow-use-after-donation
-            if hasattr(x, "is_deleted"))
-        del cache_in
-        group.arena.adopt(cache_out)
-        if not group.cache_donated:
-            group.double_buffer_bytes = max(group.double_buffer_bytes,
-                                            group.arena.live_nbytes())
-        group.toks = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)
+        self.metrics.decode_dispatches += 1
+        with TraceAnnotation("engine.decode"):
+            if srv.pool.paged:
+                # grant the page covering each live row's next write
+                # position (on-demand paging: drawn from the admission-time
+                # reservation, so this can never fail mid-decode)
+                for m in group.members:
+                    if not m.done:
+                        wpos = m.base_pos + (group.steps_done - m.join_step)
+                        srv.pool.ensure_decode_slots(group.arena, m.rows, wpos)
+                tables = group.arena.tables
+                cache_in = group.arena.relinquish()
+                logits, cache_out = group.entry.step_fn(
+                    srv.params, cache_in, group.toks, group.pos, tables)
+            else:
+                cache_in = group.arena.relinquish()
+                logits, cache_out = group.entry.step_fn(
+                    srv.params, cache_in, group.toks, group.pos)
+            # whether the step actually consumed its cache input (donation
+            # aliased the buffers): host-side flag check, no device sync —
+            # feeds the observed live-bytes watermark at group retire
+            group.cache_donated = all(  # metadata probe, never touches buffers
+                x.is_deleted() for x in jax.tree.leaves(cache_in)  # lint: allow-use-after-donation
+                if hasattr(x, "is_deleted"))
+            del cache_in
+            group.arena.adopt(cache_out)
+            if not group.cache_donated:
+                group.double_buffer_bytes = max(group.double_buffer_bytes,
+                                                group.arena.live_nbytes())
+            group.toks = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)
         if self.sync_per_tick:
-            jax.block_until_ready(group.toks)
-        group.pos = group.pos + 1
-        group.steps_done += 1
-        now = self.clock.now()
-        for m in group.members:
-            if m.done:
-                continue
-            tok = jnp.take(group.toks, m.rows_a, axis=0)
-            reason = self._register_token(m, tok, now, group.steps_done)
-            if reason is not None:
-                self._complete(m, group, now, reason)
-            elif m.emitted >= m.req.new_tokens:
-                # every mode emits exactly new_tokens outputs; they differ
-                # only in whether token #1 came from prefill or decode
-                self._complete(m, group, now, "length")
+            with TraceAnnotation("engine.wait"):
+                jax.block_until_ready(group.toks)
+        with TraceAnnotation("engine.emit"):
+            group.pos = group.pos + 1
+            group.steps_done += 1
+            now = self.clock.now()
+            for m in group.members:
+                if m.done:
+                    continue
+                tok = jnp.take(group.toks, m.rows_a, axis=0)
+                reason = self._register_token(m, tok, now, group.steps_done)
+                if reason is not None:
+                    self._complete(m, group, now, reason)
+                elif m.emitted >= m.req.new_tokens:
+                    # every mode emits exactly new_tokens outputs; they
+                    # differ only in whether token #1 came from prefill or
+                    # decode
+                    self._complete(m, group, now, "length")
 
     def _finish_record(self, handle: Optional[RequestHandle],
                        **rec) -> Dict[str, Any]:
@@ -1147,28 +1179,29 @@ class ServingEngine:
         path; then the arena goes back to the pool for reuse. Completion
         records of the group's members are annotated with the plan-level
         outcome (what ``PlanServer.handle`` reports per request)."""
-        srv = self.server
-        # the observed batch is the peak *concurrent* row usage — members
-        # joining rows another member freed never widened the batch
-        shape = InputShape(
-            f"group_{group.peak_rows}x{group.context}",
-            group.seq_bucket, group.peak_rows, "decode")
-        # an un-donated step held input + output copies of the arena at
-        # once: charge the observed watermark the second copy honestly
-        stats = srv.observed_stats(
-            group.entry, shape, group.toks,
-            double_buffer_bytes=group.double_buffer_bytes)
-        refreshed, reasons = srv.observe(group.entry.key, stats)
-        plan = (refreshed or group.entry).plan
-        for m in group.members:
-            # retiring members are finished: annotate their records with
-            # the plan-level outcome, then stop tracking their handles
-            # (user-held handles keep their buffers and .result)
-            handle = self.handles.pop(m.qr.rid, None)
-            if handle is not None and handle.result is not None:
-                handle.result.update(
-                    plan=plan, recompiled=bool(reasons),
-                    recompile_reasons=reasons,
-                    watermark_bytes=stats.watermark_bytes,
-                    pool_bytes=stats.cache_pool_bytes)
-        srv.pool.release(group.arena)
+        with TraceAnnotation("engine.retire"):
+            srv = self.server
+            # the observed batch is the peak *concurrent* row usage — members
+            # joining rows another member freed never widened the batch
+            shape = InputShape(
+                f"group_{group.peak_rows}x{group.context}",
+                group.seq_bucket, group.peak_rows, "decode")
+            # an un-donated step held input + output copies of the arena at
+            # once: charge the observed watermark the second copy honestly
+            stats = srv.observed_stats(
+                group.entry, shape, group.toks,
+                double_buffer_bytes=group.double_buffer_bytes)
+            refreshed, reasons = srv.observe(group.entry.key, stats)
+            plan = (refreshed or group.entry).plan
+            for m in group.members:
+                # retiring members are finished: annotate their records with
+                # the plan-level outcome, then stop tracking their handles
+                # (user-held handles keep their buffers and .result)
+                handle = self.handles.pop(m.qr.rid, None)
+                if handle is not None and handle.result is not None:
+                    handle.result.update(
+                        plan=plan, recompiled=bool(reasons),
+                        recompile_reasons=reasons,
+                        watermark_bytes=stats.watermark_bytes,
+                        pool_bytes=stats.cache_pool_bytes)
+            srv.pool.release(group.arena)

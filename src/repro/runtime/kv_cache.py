@@ -69,6 +69,9 @@ class PoolMetrics:
     pages_reclaimed: int = 0    # pages (leased + undrawn reservation) given
     #                             back by early exits: cancel / eos / stop
     peak_pages: int = 0         # max concurrently committed pages
+    # device bytes held by free arenas kept for recycling (a paged arena's
+    # whole slot stack stays allocated though ``total_bytes`` charges none)
+    pooled_bytes: float = 0.0
 
     def as_dict(self) -> Dict[str, float]:
         return {
@@ -85,6 +88,7 @@ class PoolMetrics:
             "pages_denied": self.pages_denied,
             "pages_reclaimed": self.pages_reclaimed,
             "peak_pages": self.peak_pages,
+            "pooled_bytes": self.pooled_bytes,
         }
 
 
@@ -502,6 +506,7 @@ class KVCachePool:
         device buffers go with them). Returns how many were evicted."""
         n = min(count, len(self._pooled))
         if n:
+            self.metrics.pooled_bytes -= sum(a.nbytes for a in self._pooled[:n])
             del self._pooled[:n]
             self.metrics.arenas_evicted += n
         return n
@@ -585,6 +590,7 @@ class KVCachePool:
                 return None
         if arena is not None:
             self._pooled.remove(arena)
+            self.metrics.pooled_bytes -= arena.nbytes
             if zero:
                 arena.cache = jax.tree.map(jnp.zeros_like, arena.cache)
             self.metrics.arenas_reused += 1
@@ -687,6 +693,7 @@ class KVCachePool:
         arena._free = list(range(arena.batch))
         arena.generation += 1
         self._pooled.append(arena)
+        self.metrics.pooled_bytes += arena.nbytes
         if len(self._pooled) > self.max_free:
             self._evict_free(len(self._pooled) - self.max_free)
 

@@ -97,7 +97,8 @@ def serve_summary(cache: PlanCacheMetrics, latency: LatencyStats) -> str:
 @dataclass
 class SchedulerMetrics:
     """Continuous-batching accounting: queueing vs. execution latency per
-    request, coalescing effectiveness, and SLO attainment.
+    request, coalescing effectiveness, SLO attainment, and the tick's
+    program counts.
 
     ``slo_s`` is the per-request total-latency objective (admission to last
     token); 0 disables SLO accounting. ``batch_slots_used`` /
@@ -119,6 +120,13 @@ class SchedulerMetrics:
     early_exits: int = 0            # completed before max_tokens (eos/stop)
     slo_met: int = 0
     slo_missed: int = 0
+    ticks: int = 0                  # calls to ServingEngine.step()
+    decode_dispatches: int = 0      # decode program calls
+    prefill_dispatches: int = 0     # prefill program calls
+    prefill_tokens: int = 0         # real prompt tokens of prefilled rows
+    prefill_slots: int = 0          # batch_bucket x seq_bucket per prefill
+    # arrival -> admission (prefill start), recorded when the request is
+    # admitted, so a request that is later cancelled still counts
     queue_latency: LatencyStats = field(default_factory=LatencyStats)
     exec_latency: LatencyStats = field(default_factory=LatencyStats)
     total_latency: LatencyStats = field(default_factory=LatencyStats)
@@ -160,9 +168,27 @@ class SchedulerMetrics:
         fragmentation benchmark gates on this)."""
         self.peak_resident = max(self.peak_resident, live_requests)
 
+    @property
+    def prefill_padding(self) -> float:
+        """Fraction of prefill slots that held padding, not prompt tokens."""
+        return (1.0 - self.prefill_tokens / self.prefill_slots
+                if self.prefill_slots else 0.0)
+
+    def observe_admission(self, queue_s: float) -> None:
+        """Queue wait of one admitted request: arrival to admission."""
+        self.queue_latency.record(queue_s)
+
+    def observe_prefill(self, prompt_tokens: int, slots: int) -> None:
+        """One prefill program call: the real prompt tokens it carried and
+        the ``batch_bucket x seq_bucket`` slots it computed."""
+        self.prefill_dispatches += 1
+        self.prefill_tokens += prompt_tokens
+        self.prefill_slots += slots
+
     def observe_first_token(self, ttft_s: float) -> None:
-        """Time-to-first-token: request admission to its first TokenEvent
-        (the latency a streaming consumer actually perceives)."""
+        """Time-to-first-token: request arrival to its first TokenEvent,
+        stamped once the program that produced the token has finished (the
+        latency a streaming consumer actually perceives)."""
         self.ttft_latency.record(ttft_s)
 
     def observe_token_gap(self, gap_s: float) -> None:
@@ -171,9 +197,9 @@ class SchedulerMetrics:
         self.itl_latency.record(gap_s)
 
     def observe_request(self, queue_s: float, exec_s: float) -> None:
+        """A completed request (its queue wait was observed at admission)."""
         self.completed += 1
         total = queue_s + exec_s
-        self.queue_latency.record(queue_s)
         self.exec_latency.record(exec_s)
         self.total_latency.record(total)
         if self.slo_s > 0:
@@ -189,7 +215,10 @@ class SchedulerMetrics:
                 f"coalesced={self.coalesced_requests} "
                 f"joins={self.joins} join_rows={self.join_rows} "
                 f"peak_resident={self.peak_resident} "
-                f"bucket_fill={self.bucket_fill:.2f}  |  "
+                f"bucket_fill={self.bucket_fill:.2f} "
+                f"ticks={self.ticks} decodes={self.decode_dispatches} "
+                f"prefills={self.prefill_dispatches} "
+                f"prefill_pad={self.prefill_padding:.2f}  |  "
                 f"queue p50={self.queue_latency.percentile(50) * ms:.1f}ms "
                 f"p95={self.queue_latency.percentile(95) * ms:.1f}ms  "
                 f"exec p50={self.exec_latency.percentile(50) * ms:.1f}ms "
@@ -232,6 +261,11 @@ def merge_scheduler_metrics(parts) -> "SchedulerMetrics":
         out.early_exits += m.early_exits
         out.slo_met += m.slo_met
         out.slo_missed += m.slo_missed
+        out.ticks += m.ticks
+        out.decode_dispatches += m.decode_dispatches
+        out.prefill_dispatches += m.prefill_dispatches
+        out.prefill_tokens += m.prefill_tokens
+        out.prefill_slots += m.prefill_slots
         for dst, src in ((out.queue_latency, m.queue_latency),
                          (out.exec_latency, m.exec_latency),
                          (out.total_latency, m.total_latency),
@@ -295,6 +329,7 @@ def pool_summary(pool) -> str:
             f"rows_reused={m.rows_reused} handoffs={m.handoff_writes} "
             f"occupancy={pool.occupancy():.2f} "
             f"live={pool.live_bytes() / mib:.1f}MiB "
+            f"pooled={m.pooled_bytes / mib:.1f}MiB "
             f"peak={m.peak_bytes / mib:.1f}MiB")
     if getattr(pool, "paged", False):
         line += (f"\nkv_pages: size={pool.page_size} "

@@ -22,6 +22,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.config import (InputShape, MeshConfig, ModelConfig, TPU_V5E,
@@ -302,9 +303,12 @@ class PlanServer:
 
     def _compile_entry(self, key: PlanKey) -> CacheEntry:
         t0 = time.perf_counter()
-        plan = self.compiler.compile(self.cfg, key.bucket_shape(),
-                                     self.mesh_cfg, dtype=self.dtype_name)
-        entry = CacheEntry(key=key, plan=plan, step_fn=self._build_step(plan))
+        # planner walk + jit wrapper; XLA compiles at the first call
+        with TraceAnnotation("engine.plan"):
+            plan = self.compiler.compile(self.cfg, key.bucket_shape(),
+                                         self.mesh_cfg, dtype=self.dtype_name)
+            entry = CacheEntry(key=key, plan=plan,
+                               step_fn=self._build_step(plan))
         self.metrics.compile_seconds += time.perf_counter() - t0
         return entry
 
@@ -341,13 +345,15 @@ class PlanServer:
         families without handoff). ``lengths`` is the per-row prompt length
         inside the padded bucket (default: the full bucket width)."""
         b, s = entry.key.batch_bucket, entry.key.seq_bucket
-        if tokens is None:
-            tokens = jnp.ones((b, s), jnp.int32)
-        if lengths is None:
-            lengths = jnp.full((b,), s, jnp.int32)
-        logits, kv = entry.step_fn(
-            self.params, {"tokens": tokens, "lengths": lengths})
-        jax.block_until_ready(logits)
+        with TraceAnnotation("engine.prefill"):
+            if tokens is None:
+                tokens = jnp.ones((b, s), jnp.int32)
+            if lengths is None:
+                lengths = jnp.full((b,), s, jnp.int32)
+            logits, kv = entry.step_fn(
+                self.params, {"tokens": tokens, "lengths": lengths})
+        with TraceAnnotation("engine.wait"):
+            jax.block_until_ready(logits)
         return logits, kv
 
     def prefill_first_token(self, batch: int, context: int,

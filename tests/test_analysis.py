@@ -229,6 +229,16 @@ def test_sanitizer_catches_live_bytes_drift(live_engine):
         arena.allocator.reserved -= 1
 
 
+def test_sanitizer_catches_pooled_bytes_drift(live_engine):
+    pool = live_engine.server.pool
+    pool.metrics.pooled_bytes += 4096  # gauge drifts from the free list
+    try:
+        assert "pooled-bytes-drift" in _rules(check_pool(pool))
+    finally:
+        pool.metrics.pooled_bytes -= 4096
+    assert check_engine(live_engine) == []
+
+
 def test_sanitizer_catches_handle_leak(live_engine):
     live_engine.handles[999_999] = object()  # retired-but-tracked handle
     try:

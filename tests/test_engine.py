@@ -11,6 +11,7 @@ import pytest
 
 from repro.configs import get_config
 from repro.runtime.engine import ServingEngine, WallClock
+from repro.runtime.engine_config import EngineConfig
 from repro.runtime.scheduler import (ContinuousBatchingScheduler,
                                      simulate_arrivals)
 from repro.runtime.serve_loop import PlanServer, ServeRequest
@@ -232,3 +233,70 @@ def test_rid_stamped_at_construction():
     assert h.rid == r2.rid == h.result["rid"] == h.qr.rid
     out = srv.handle(r1)
     assert out["rid"] == r1.rid
+
+
+# ---------------------------------------------------------------------------
+# time stamps: first token after prefill, queue wait at admission
+# ---------------------------------------------------------------------------
+
+
+class _StepClock:
+    """Engine clock that moves only when the test moves it."""
+
+    def __init__(self):
+        self.t = 0.0
+
+    def now(self) -> float:
+        return self.t
+
+    def advance_to(self, t: float) -> None:
+        self.t = max(self.t, t)
+
+
+def _slow_prefill(srv, clock, seconds):
+    run_prefill = srv.run_prefill
+
+    def slow(entry, tokens=None, lengths=None):
+        clock.t += seconds
+        return run_prefill(entry, tokens, lengths)
+
+    srv.run_prefill = slow
+
+
+def test_first_token_is_stamped_after_prefill():
+    """The prefill token exists once the prefill program has run: its event
+    time and the recorded TTFT include a slow prefill, while the queue wait
+    ends at admission, before it."""
+    srv = PlanServer(CFG, config=EngineConfig())
+    clock = _StepClock()
+    eng = ServingEngine(srv, clock=clock)
+    _slow_prefill(srv, clock, 5.0)
+    h = eng.submit(ServeRequest(1, 20, 3))     # arrives at 0
+    clock.t = 1.0
+    events = eng.step()
+    first = next(e for e in events if e.rid == h.rid and e.index == 0)
+    assert first.t == 6.0
+    assert eng.metrics.ttft_latency.samples == [6.0]
+    assert h.qr.start_s == 1.0
+    assert eng.metrics.queue_latency.samples == [1.0]
+    eng.drain()
+    assert eng.metrics.queue_latency.count == 1   # not again at completion
+
+
+def test_queue_wait_counts_requests_that_never_complete():
+    """A request admitted and then cancelled still waited in the queue; a
+    request still queued has no admission yet."""
+    srv = PlanServer(CFG, config=EngineConfig(pool_max_arenas=1))
+    clock = _StepClock()
+    eng = ServingEngine(srv, clock=clock)
+    a = eng.submit(ServeRequest(1, 20, 8))
+    clock.t = 2.0
+    eng.step()
+    b = eng.submit(ServeRequest(1, 100, 8))   # another bucket: no arena
+    eng.step()
+    assert b.state == "queued"
+    assert eng.cancel(a)
+    assert eng.metrics.completed == 0
+    assert eng.metrics.queue_latency.samples == [2.0]
+    eng.drain()
+    assert eng.metrics.queue_latency.count == 2

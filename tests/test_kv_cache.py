@@ -14,6 +14,7 @@ from repro.core.plan_cache import BucketPolicy, recompile_reasons
 from repro.core.strategies import RuntimeStats
 from repro.models.model import build_model
 from repro.runtime.kv_cache import KVCachePool
+from repro.runtime.metrics import pool_summary
 from repro.runtime.scheduler import (ContinuousBatchingScheduler,
                                      RequestQueue, simulate_arrivals)
 from repro.runtime.serve_loop import PlanServer, ServeRequest
@@ -433,3 +434,25 @@ def test_observed_stats_row_granular_pool_charges_arena():
         entry, InputShape("t", 64, 2, "decode"), jnp.ones((2, 1), jnp.int32))
     assert stats.cache_pool_bytes == pytest.approx(arena.nbytes)
     srv.pool.release(arena)
+
+
+def test_pool_gauge_counts_free_paged_arenas():
+    """A free paged arena keeps its whole slot stack on the device though
+    the budget charges it nothing: ``pooled_bytes`` shows those bytes while
+    the arena waits in the free pool, and admission is unchanged."""
+    pool = _pool(page_size=16, max_free=1)
+    a = pool.acquire(2, 64)
+    held = sum(x.nbytes for x in a.cache.values())
+    assert a.nbytes == held > 0
+    assert pool.metrics.pooled_bytes == 0
+    pool.release(a)
+    assert pool.metrics.pooled_bytes == held
+    assert pool.total_bytes() == 0            # still page-exact
+    b = pool.acquire(2, 64)                   # recycled: leaves the free pool
+    assert b is a and pool.metrics.pooled_bytes == 0
+    c = pool.acquire(1, 32)
+    pool.release(b)
+    pool.release(c)                           # max_free=1 evicts b
+    assert pool.metrics.arenas_evicted == 1
+    assert pool.metrics.pooled_bytes == c.nbytes
+    assert "pooled=" in pool_summary(pool)
