@@ -316,7 +316,7 @@ class RequestQueue:
         self._pending = sorted(self._pending + list(members),
                                key=lambda qr: (qr.arrival_s, qr.rid))
 
-    def take_joinable(self, seq_bucket: int, max_rows: int,
+    def take_joinable(self, seq_bucket: Optional[int], max_rows: int,
                       fits=None) -> List[QueuedRequest]:
         """Pop pending same-bucket requests that fit in ``max_rows`` free
         arena rows, strictly FIFO *within the bucket*: scanning stops at
@@ -324,10 +324,18 @@ class RequestQueue:
         arrivals can never leapfrog a wide head of their own bucket forever
         (the no-starvation guarantee extends to mid-decode joins).
 
+        ``seq_bucket=None``: the group takes any bucket (its cache has no
+        length axis); the joiners still come from one bucket, the one
+        ``next_group`` would pick, so their prefill stays bucket-pure.
+
         ``fits(qr)``: extra admission predicate (free cache pages, byte
         budget); it may track cumulative commitments across accepted
         candidates — it is called once per candidate, in scan order, and a
         False return stops the scan like an unfitting batch does."""
+        if seq_bucket is None:
+            if not self._pending:
+                return []
+            seq_bucket = self._select_bucket()
         taken: List[QueuedRequest] = []
         room = max_rows
         for qr in list(self._pending):
@@ -477,6 +485,17 @@ class _Group:
         return sum(m.req.batch for m in self.members)
 
 
+def cache_has_length_axis(model) -> bool:
+    """Whether a model's decode cache grows with the context. Attention K/V,
+    a sliding window's included, holds one slot more for a 2-token context
+    than for a 1-token one; recurrent state and conv tails (SSD) hold the
+    same whatever the context."""
+    def shapes(seq: int):
+        return {k: shape for k, (shape, _axes, _dt)
+                in model.cache_entries(1, seq).items()}
+    return shapes(1) != shapes(2)
+
+
 # ===========================================================================
 # the client protocol
 # ===========================================================================
@@ -536,6 +555,14 @@ class ServingEngine:
     advance every active group by one decode step — emitting a
     :class:`TokenEvent` per live request.
 
+    A model whose decode cache has no length axis (SSD state,
+    :func:`cache_has_length_axis`) decodes every seq bucket alike, so with
+    mid-decode joins on (``seq_free``) its groups are not keyed by seq
+    bucket: a group leases ``max_group_batch`` rows at one fixed decode
+    shape, joiners of any bucket fill its free rows (one bucket per group
+    per tick, so each join prefill stays bucket-pure), and another group
+    forms only when no active group has a free row.
+
     Mode flags (the adapters differ only in these):
 
     - ``prefill``: run the cached-prefill prompt pass at admission and seed
@@ -586,6 +613,11 @@ class ServingEngine:
             select=self.config.bucket_select)
         self.metrics = SchedulerMetrics(slo_s=self.config.slo_ms / 1e3)
         self.join_mid_decode = self.config.join_mid_decode
+        # only joins can fill rows beyond a group's formation batch, so the
+        # one-group path needs them; without joins every family keeps its
+        # per-bucket groups
+        self.seq_free = (self.join_mid_decode
+                         and not cache_has_length_axis(server.model))
         self.prefill = prefill
         self.count_first = count_first
         self.eager_pages = eager_pages
@@ -977,8 +1009,15 @@ class ServingEngine:
         srv = self.server
         handoff = self.prefill and srv.model.supports_handoff
         total_batch = sum(qr.req.batch for qr in queued)
-        span = max(srv.request_span(qr.req) for qr in queued)
-        entry = srv.decode_entry(total_batch, span)
+        if self.seq_free:
+            # one decode shape for every bucket: the rows the pool budget
+            # is sized for, at the policy's smallest seq key
+            entry = srv.decode_entry(
+                max(total_batch, self.config.max_group_batch),
+                srv.policy.min_seq)
+        else:
+            entry = srv.decode_entry(
+                total_batch, max(srv.request_span(qr.req) for qr in queued))
         b, s = entry.key.batch_bucket, entry.key.seq_bucket
         # page-exact admission demand: what this group's members commit
         # (rows + span pages), not the arena's bucket-shaped capacity
@@ -1015,7 +1054,8 @@ class ServingEngine:
         continuous batching). Joiners skip the line only for capacity the
         head-of-line request could not use anyway — its own group still
         forms through ``next_group`` as soon as the pool can lease an
-        arena."""
+        arena. A ``seq_free`` group takes the bucket the queue selects,
+        whatever its own."""
         with TraceAnnotation("engine.joins"):
             srv = self.server
             arena = group.arena
@@ -1046,7 +1086,8 @@ class ServingEngine:
                     self._page_denied_rids.discard(qr.rid)
                     return True
 
-            queued = self.queue.take_joinable(group.seq_bucket, free, fits=fits)
+            queued = self.queue.take_joinable(
+                None if self.seq_free else group.seq_bucket, free, fits=fits)
             if not queued:
                 return
             members = self._admit_members(group, queued, group.steps_done,
@@ -1058,6 +1099,8 @@ class ServingEngine:
         with TraceAnnotation("engine.form"):
             if not len(self.queue):
                 return
+            if self.seq_free and any(g.arena.rows_free for g in self.active):
+                return              # free rows take pending work as joins
             queued = self.queue.next_group()
             if not queued:
                 return
@@ -1079,6 +1122,8 @@ class ServingEngine:
         """
         srv = self.server
         self.metrics.decode_dispatches += 1
+        self.metrics.decode_rows += sum(m.req.batch for m in group.members
+                                        if not m.done)
         with TraceAnnotation("engine.decode"):
             if srv.pool.paged:
                 # grant the page covering each live row's next write

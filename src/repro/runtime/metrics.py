@@ -122,6 +122,7 @@ class SchedulerMetrics:
     slo_missed: int = 0
     ticks: int = 0                  # calls to ServingEngine.step()
     decode_dispatches: int = 0      # decode program calls
+    decode_rows: int = 0            # live rows those calls carried
     prefill_dispatches: int = 0     # prefill program calls
     prefill_tokens: int = 0         # real prompt tokens of prefilled rows
     prefill_slots: int = 0          # batch_bucket x seq_bucket per prefill
@@ -167,6 +168,12 @@ class SchedulerMetrics:
         residency the pool budget actually admitted (the paged-vs-dense
         fragmentation benchmark gates on this)."""
         self.peak_resident = max(self.peak_resident, live_requests)
+
+    @property
+    def rows_per_decode(self) -> float:
+        """Mean live rows a decode program carried."""
+        return (self.decode_rows / self.decode_dispatches
+                if self.decode_dispatches else 0.0)
 
     @property
     def prefill_padding(self) -> float:
@@ -217,6 +224,7 @@ class SchedulerMetrics:
                 f"peak_resident={self.peak_resident} "
                 f"bucket_fill={self.bucket_fill:.2f} "
                 f"ticks={self.ticks} decodes={self.decode_dispatches} "
+                f"rows/decode={self.rows_per_decode:.2f} "
                 f"prefills={self.prefill_dispatches} "
                 f"prefill_pad={self.prefill_padding:.2f}  |  "
                 f"queue p50={self.queue_latency.percentile(50) * ms:.1f}ms "
@@ -263,6 +271,7 @@ def merge_scheduler_metrics(parts) -> "SchedulerMetrics":
         out.slo_missed += m.slo_missed
         out.ticks += m.ticks
         out.decode_dispatches += m.decode_dispatches
+        out.decode_rows += m.decode_rows
         out.prefill_dispatches += m.prefill_dispatches
         out.prefill_tokens += m.prefill_tokens
         out.prefill_slots += m.prefill_slots

@@ -383,15 +383,16 @@ class EngineRouter:
         starvation-freedom property test)."""
         eng, srv = r.engine, r.server
         sb = eng.queue.seq_bucket(req)
-        # rows already spoken for by queued same-bucket work: a joiner
-        # only truly fits if capacity remains after the earlier queue
-        # would be seated (conservative, keeps placement FIFO-honest)
+        # rows already spoken for by queued work that could join the same
+        # groups (same-bucket, or any on a seq-free engine): a joiner only
+        # truly fits if capacity remains after the earlier queue would be
+        # seated (conservative, keeps placement FIFO-honest)
         queued_rows = sum(qr.req.batch for qr in eng.queue.pending
-                          if eng.queue.seq_bucket(qr.req) == sb)
+                          if eng.seq_free or eng.queue.seq_bucket(qr.req) == sb)
         can_join = False
         if eng.join_mid_decode:
             for g in eng.active:
-                if g.seq_bucket != sb:
+                if g.seq_bucket != sb and not eng.seq_free:
                     continue
                 if g.arena.rows_free - queued_rows < req.batch:
                     continue

@@ -10,7 +10,9 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config
-from repro.runtime.engine import ServingEngine, WallClock
+from repro.models.model import build_model
+from repro.runtime.engine import (ServingEngine, WallClock,
+                                  cache_has_length_axis)
 from repro.runtime.engine_config import EngineConfig
 from repro.runtime.scheduler import (ContinuousBatchingScheduler,
                                      simulate_arrivals)
@@ -300,3 +302,190 @@ def test_queue_wait_counts_requests_that_never_complete():
     assert eng.metrics.queue_latency.samples == [2.0]
     eng.drain()
     assert eng.metrics.queue_latency.count == 2
+
+
+# ---------------------------------------------------------------------------
+# one decode group for a decode cache with no length axis (SSD)
+# ---------------------------------------------------------------------------
+
+
+SSD = get_config("mamba2-1.3b-smoke")
+# (context, new_tokens) spanning three seq buckets: 32, 64 and 128
+MIXED = ((20, 4), (40, 5), (100, 3))
+
+
+@pytest.fixture(scope="module")
+def ssd_srv():
+    return PlanServer(SSD, config=EngineConfig(dtype="float32",
+                                               cache_capacity=32,
+                                               prefill=True, sanitize=True))
+
+
+def _alone(srv, ctx, new):
+    """Tokens of one request served by itself through the sequential
+    adapter: no joins, its own (batch, span) bucket, a 1-row decode."""
+    return np.asarray(srv.handle(ServeRequest(1, ctx, new))["tokens"])
+
+
+def _record_admissions(eng):
+    """Wrap ``_admit_members``: one ``(tick, [seq buckets of the admitted
+    requests], prefill seq bucket)`` per admission."""
+    log, admit, run_prefill = [], eng._admit_members, eng.server.run_prefill
+    seen = {}
+
+    def prefill(entry, tokens=None, lengths=None):
+        seen["s"] = entry.key.seq_bucket
+        return run_prefill(entry, tokens, lengths)
+
+    def admit_members(group, queued, join_step, now):
+        seen.clear()
+        out = admit(group, queued, join_step, now)
+        log.append((eng.metrics.ticks,
+                    [eng.queue.seq_bucket(qr.req) for qr in queued],
+                    seen.get("s")))
+        return out
+
+    eng._admit_members = admit_members
+    eng.server.run_prefill = prefill
+    return log
+
+
+@pytest.mark.parametrize("arch,seq_free", [
+    ("yi-6b", False), ("mamba2-1.3b", True), ("recurrentgemma-2b", False),
+    ("dbrx-132b", False), ("whisper-medium", False), ("internvl2-2b", False)])
+def test_length_axis_read_from_the_cache_spec(arch, seq_free):
+    """Only a cache whose shapes ignore the context takes the one-group
+    path: attention, sliding-window attention (the hybrid's), moe and
+    enc-dec caches all grow with it."""
+    model = build_model(get_config(arch + "-smoke"))
+    assert cache_has_length_axis(model) is not seq_free
+
+
+def test_ssd_buckets_share_one_arena_and_one_decode_per_tick(ssd_srv):
+    srv = ssd_srv
+    created = srv.pool.metrics.arenas_created
+    eng = ServingEngine(srv)
+    assert eng.seq_free
+    hs = [eng.submit(ServeRequest(1, c, n)) for c, n in MIXED]
+    assert len({eng.queue.seq_bucket(h.req) for h in hs}) == 3
+    arenas = set()
+    while not eng.idle:
+        eng.step()
+        assert len(eng.active) <= 1
+        arenas |= {id(g.arena) for g in eng.active}
+        for g in eng.active:
+            assert (g.arena.batch, g.arena.seq) == (8, srv.policy.min_seq)
+    assert len(arenas) == 1 and eng.metrics.groups == 1
+    assert srv.pool.metrics.arenas_created - created <= 1
+    assert eng.metrics.joins == 2
+    # fewer than max_group_batch rows live: one decode program per tick
+    assert eng.metrics.decode_dispatches == eng.metrics.ticks
+    for h in hs:
+        assert h.result["bucket"] == (8, srv.policy.min_seq)
+        assert h.result["finish_reason"] == "length"
+    # each request's greedy tokens are those it gets served alone
+    for h, (c, n) in zip(hs, MIXED):
+        np.testing.assert_array_equal(np.asarray(h.result["tokens"]),
+                                      _alone(srv, c, n))
+
+
+def test_ssd_joiner_from_another_bucket_matches_alone(ssd_srv):
+    """A joiner of another bucket lands on the row a finished member freed
+    and decodes exactly as it does alone: the handoff overwrites the row's
+    recurrent state wholesale."""
+    srv = ssd_srv
+    eng = ServingEngine(srv)
+    a = eng.submit(ServeRequest(1, 20, 10))
+    b = eng.submit(ServeRequest(1, 24, 2))      # done after one decode step
+    eng.step()
+    b_rows = eng._member_of(b.rid).rows
+    eng.step()
+    assert b.result is not None
+    c = eng.submit(ServeRequest(1, 100, 4))     # bucket 128, not 32
+    eng.step()
+    member = eng._member_of(c.rid)
+    assert member.rows == b_rows and member.join_step >= 1
+    assert eng.queue.seq_bucket(c.req) != eng.queue.seq_bucket(a.req)
+    eng.drain()
+    assert eng.metrics.groups == 1
+    np.testing.assert_array_equal(np.asarray(c.result["tokens"]),
+                                  _alone(srv, 100, 4))
+    np.testing.assert_array_equal(np.asarray(a.result["tokens"]),
+                                  _alone(srv, 20, 10))
+
+
+def test_ssd_join_prefills_are_bucket_pure(ssd_srv):
+    """Joiners of several buckets pending in one tick: the group admits one
+    bucket per tick, in one prefill keyed on that bucket."""
+    srv = ssd_srv
+    eng = ServingEngine(srv)
+    log = _record_admissions(eng)
+    eng.submit(ServeRequest(1, 20, 12))
+    eng.step()
+    for c in (40, 100, 44, 110, 20):            # buckets 64, 128, 64, 128, 32
+        eng.submit(ServeRequest(1, c, 6))
+    eng.drain()
+    assert eng.metrics.groups == 1
+    ticks = [t for t, _sbs, _s in log]
+    assert len(ticks) == len(set(ticks))        # one admission per tick
+    for _t, sbs, s in log:
+        assert len(set(sbs)) == 1 and sbs[0] == s
+    # hol: the oldest pending request picks the bucket, riders of its
+    # bucket come along
+    assert [sbs for _t, sbs, _s in log] == [[32], [64, 64], [128, 128], [32]]
+
+
+def test_ssd_second_group_forms_only_when_the_first_is_full(ssd_srv):
+    srv = ssd_srv
+    eng = ServingEngine(srv)
+    hs = [eng.submit(ServeRequest(1, c, 8)) for c in (20, 40, 100) * 3 + (40,)]
+    while len(eng.active) < 2 and not eng.idle:
+        eng.step()
+    first, second = eng.active
+    assert first.arena.rows_free == 0
+    assert (second.arena.batch, second.arena.seq) == (8, srv.policy.min_seq)
+    eng.drain()
+    assert all(h.result["finish_reason"] == "length" for h in hs)
+    assert eng.metrics.groups == 2
+
+
+@pytest.mark.parametrize("arch", ["yi-6b", "recurrentgemma-2b"])
+def test_attention_groups_stay_keyed_by_seq_bucket(arch):
+    srv = PlanServer(get_config(arch + "-smoke"),
+                     config=EngineConfig(dtype="float32", cache_capacity=32,
+                                         sanitize=True))
+    eng = ServingEngine(srv)
+    assert not eng.seq_free
+    log = _record_admissions(eng)
+    hs = [eng.submit(ServeRequest(1, c, n)) for c, n in MIXED]
+    eng.drain()
+    buckets = [eng.queue.seq_bucket(h.req) for h in hs]
+    assert len(set(buckets)) == 3
+    # one group per bucket, each decoding in an arena of its own bucket
+    assert eng.metrics.groups == 3 and eng.metrics.joins == 0
+    assert [h.result["bucket"] for h in hs] == [(1, s) for s in buckets]
+    assert [sbs for _t, sbs, _s in log] == [[s] for s in buckets]
+
+
+@pytest.mark.parametrize("arch", ["yi-6b", "mamba2-1.3b"])
+def test_decode_rows_counts_live_rows_per_decode(arch):
+    srv = PlanServer(get_config(arch + "-smoke"),
+                     config=EngineConfig(dtype="float32", cache_capacity=32))
+    eng = ServingEngine(srv)
+    live, tick = [], eng._phase_tick
+
+    def counting_tick(group):
+        live.append(sum(m.req.batch for m in group.members if not m.done))
+        return tick(group)
+
+    eng._phase_tick = counting_tick
+    eng.submit(ServeRequest(2, 20, 6))
+    eng.step()
+    eng.submit(ServeRequest(1, 24, 3))
+    eng.submit(ServeRequest(1, 100, 4))
+    eng.drain()
+    m = eng.metrics
+    assert m.decode_dispatches == len(live)
+    assert m.decode_rows == sum(live)
+    assert m.rows_per_decode == pytest.approx(np.mean(live))
+    assert f"rows/decode={np.mean(live):.2f}" in eng.summary()

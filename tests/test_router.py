@@ -174,6 +174,26 @@ def test_placement_determinism_property(shapes):
     assert decisions[0] == decisions[1]
 
 
+@pytest.mark.parametrize("arch,reason,same_replica", [
+    ("mamba2-1.3b", "join", True), ("yi-6b", "idle", False)])
+def test_placement_joins_other_buckets_only_where_the_cache_has_no_length(
+        arch, reason, same_replica):
+    """A request of another seq bucket can join an in-flight group on an
+    engine whose decode cache has no length axis; on an attention engine
+    it cannot, and the idle replica takes it."""
+    ecfg = EngineConfig(replicas=2, dtype="float32")
+    router = EngineRouter([ecfg.build_server(get_config(arch + "-smoke"))
+                           for _ in range(2)], config=ecfg)
+    router.submit(ServeRequest(1, 20, 8))
+    router.step()
+    first, = (r.idx for r in router.replicas if r.engine.active)
+    router.submit(ServeRequest(1, 100, 4))        # bucket 128, not 32
+    d = router.decisions[-1]
+    assert (d.reason, d.replica == first) == (reason, same_replica)
+    router.drain()
+    assert not router.handles
+
+
 # ---------------------------------------------------------------------------
 # starvation-freedom: no replica idles while another holds queued work
 # ---------------------------------------------------------------------------
